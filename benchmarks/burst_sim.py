@@ -56,6 +56,7 @@ from repro import configs, protection  # noqa: E402
 from repro.models import lm  # noqa: E402
 from repro.serving import frontend, kvcache, protected  # noqa: E402
 from repro.serving import telemetry  # noqa: E402
+from repro.launch.compile_cache import setup_compile_cache  # noqa: E402
 
 
 def _cell_tag(policy: str, rate: float, scrub_every: int = 0,
@@ -317,6 +318,7 @@ def main(argv=None):
                          "matmuls) and price it in the abft_slo section")
     ap.add_argument("--out-dir", default=None)
     args = ap.parse_args(argv)
+    setup_compile_cache()
 
     if args.smoke:
         # one page per slot (prompt+gen <= 10 < page_size 16): keeps the

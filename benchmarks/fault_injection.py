@@ -22,6 +22,7 @@ import time
 import jax
 
 from repro import protection
+from repro.launch.compile_cache import setup_compile_cache
 from repro.training.cnn_experiments import (eval_policy, run_scheme_campaign,
                                             train_cnn_wot)
 
@@ -130,6 +131,7 @@ def main(argv=None):
                          "COMPUTE faults (accumulator SDCs and decoded-"
                          "weight corruption), per target")
     args = ap.parse_args(argv)
+    setup_compile_cache()
     t0 = time.time()
     results = run(models=tuple(args.models), trials=args.trials,
                   batch=args.batch, json_path=args.json, policy=args.policy,

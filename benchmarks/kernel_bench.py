@@ -1,9 +1,11 @@
-"""Kernel micro-benchmarks (CPU timings are for the pure-jnp reference path;
-Pallas kernels run in interpret mode here — TPU perf comes from the roofline
-analysis, not wall-clock on this host).
+"""Kernel micro-benchmarks. On a CPU host the Pallas kernels run in the
+interpreter, so their timings say nothing about the chip; the structural
+rooflines are then computed for ``peaks.TARGET_KIND``. On a TPU they use
+the attached chip's published peaks (``benchmarks/peaks.py``; an unknown
+chip is an error).
 
 Reports, per kernel: reference-path us/call and the STRUCTURAL cost of the
-kernel on TPU v5e (bytes moved, flops, roofline-bound time).
+kernel (bytes moved, flops, roofline-bound time).
 
 ``--json BENCH_kernels.json`` additionally times the in-place decode on BOTH
 backends per weight shape, sweeps fused decode+matmul tiles for the float
@@ -17,9 +19,9 @@ writes the ``bench_kernels/v6`` artifact that
 ``protection.AutotuneTable`` consumes — per-leaf backend AND tile choices
 (float ``tiles`` + ``int8_tiles``) are then reproducible from a checked-in
 file instead of call-site defaults (``--tiles-smoke`` shrinks the sweep for
-CI).  On a CPU host the Pallas timings are interpret-mode (always slower —
-recorded, with ``pallas_interpret: true``, so a TPU re-run can overwrite
-them).
+CI).  The artifact records the device and whether the kernels were
+interpreted (``pallas_interpret``), so a TPU re-run can overwrite a CPU
+table.
 """
 from __future__ import annotations
 
@@ -33,11 +35,26 @@ import numpy as np
 
 from repro import protection
 from repro.core import ecc
-from repro.kernels import ref
+from repro.kernels import platform, ref
+from repro.launch.compile_cache import setup_compile_cache
 
-PEAK_BW = 819e9        # v5e HBM B/s
-PEAK_FLOPS = 197e12    # v5e bf16 FLOP/s
-PEAK_INT8 = 394e12
+try:                      # run as a script or imported as benchmarks.*
+    from . import peaks as _peaks
+except ImportError:
+    import peaks as _peaks
+
+
+def _device_peaks() -> tuple:
+    """(device kind the rooflines are for, its published peaks): the
+    attached TPU, or the structural target on a host without one."""
+    dev = jax.devices()[0]
+    kind = dev.device_kind if dev.platform == "tpu" else _peaks.TARGET_KIND
+    return kind, _peaks.peaks(kind)
+
+
+_KIND, _P = _device_peaks()
+PEAK_BW = _P["hbm_bytes_per_s"]
+PEAK_INT8 = _P["int8_ops"]
 
 
 def _time(f, *args, reps=5):
@@ -313,7 +330,7 @@ def write_bench_kernels(path, entries=None, *, tile_sweep=TILE_SWEEP,
     """Write BENCH_kernels.json in the ``bench_kernels/v6`` schema that
     ``protection.AutotuneTable`` loads (validated by round-tripping through
     it before writing)."""
-    platform = jax.devices()[0].platform
+    dev = jax.devices()[0]
     if entries is None:
         entries = bench_backend_decode()
         if tile_sweep:
@@ -323,8 +340,9 @@ def write_bench_kernels(path, entries=None, *, tile_sweep=TILE_SWEEP,
     if attention_long is None:
         attention_long, crossover = bench_chunked_attention()
     payload = {"schema": protection.BENCH_KERNELS_SCHEMA,
-               "platform": platform,
-               "pallas_interpret": platform != "tpu",
+               "platform": dev.platform,
+               "device_kind": dev.device_kind,
+               "pallas_interpret": platform.interpret(),
                "op": "in-place-decode64+fused-qmatmul",
                "entries": entries,
                "attention": attention,
@@ -348,6 +366,8 @@ def main(argv=None):
                     help="tiny fused-tile sweep + short attention lengths "
                          "(CI smoke; interpret mode)")
     args = ap.parse_args(argv)
+    setup_compile_cache()
+    print(f"# rooflines for {_KIND}")
     us, b, r = bench_decode()
     print(f"kernel_ecc_decode,{us:.0f},tpu_roofline_us={r:.1f}_bytes={b}")
     us, fl, r = bench_qmatmul()

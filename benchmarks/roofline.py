@@ -1,4 +1,5 @@
-"""Roofline analysis from the dry-run JSONL (EXPERIMENTS.md §Roofline).
+"""Roofline analysis from the dry-run JSONL (EXPERIMENTS.md §Roofline),
+structural, for the target chip of ``benchmarks/peaks.py``.
 
 Per (arch x shape x mesh):
   compute    = HLO_FLOPs / (chips * 197 TFLOP/s bf16)
@@ -19,10 +20,16 @@ import numpy as np
 from repro import configs
 from repro.models.config import SHAPES
 
-PEAK_FLOPS = 197e12   # bf16/chip
-PEAK_INT8 = 394e12    # int8/chip
-HBM_BW = 819e9        # B/s/chip
-LINK_BW = 50e9        # B/s/link ICI
+try:                      # run as a script or imported as benchmarks.roofline
+    from . import peaks as _peaks
+except ImportError:
+    import peaks as _peaks
+
+_P = _peaks.peaks(_peaks.TARGET_KIND)
+PEAK_FLOPS = _P["bf16_flops"]
+PEAK_INT8 = _P["int8_ops"]
+HBM_BW = _P["hbm_bytes_per_s"]
+LINK_BW = _P["ici_link_bytes_per_s"]
 
 
 def param_counts(cfg):

@@ -28,14 +28,17 @@ def _block_view(flat: jnp.ndarray) -> tuple[jnp.ndarray, int]:
     return flat.reshape(-1, BLOCK), pad
 
 
-def throttle_q(q_flat: jnp.ndarray) -> jnp.ndarray:
-    """Clamp positions 0..6 of each 8-value block to [-64, 63] (int domain)."""
-    blocks, pad = _block_view(q_flat)
-    pos = jnp.arange(BLOCK)
-    clamped = jnp.clip(blocks, WOT_LO, WOT_HI)
-    blocks = jnp.where(pos == BLOCK - 1, blocks, clamped)
-    out = blocks.reshape(-1)
-    return out[: q_flat.shape[0]] if pad else out
+def throttle_q(q: jnp.ndarray) -> jnp.ndarray:
+    """Clamp positions 0..6 of each 8-value block to [-64, 63] (int domain).
+
+    Blocks run along the last axis when it holds whole blocks — then the
+    array keeps its shape (and device layout) throughout; otherwise they
+    run over the flattened values, as the flat-padded layout stores them."""
+    if q.ndim == 0 or q.shape[-1] % BLOCK:
+        flat = jnp.pad(jnp.reshape(q, -1), (0, (-q.size) % BLOCK))
+        return throttle_q(flat)[: q.size].reshape(q.shape)
+    pos = jax.lax.broadcasted_iota(jnp.int32, q.shape, q.ndim - 1)
+    return jnp.where(pos % BLOCK == BLOCK - 1, q, jnp.clip(q, WOT_LO, WOT_HI))
 
 
 def throttle_tensor(w: jnp.ndarray, scale=None) -> jnp.ndarray:
@@ -47,7 +50,7 @@ def throttle_tensor(w: jnp.ndarray, scale=None) -> jnp.ndarray:
     if scale is None:
         scale = quant.compute_scale(w)
     q = jnp.clip(jnp.round(w / scale), -quant.QMAX, quant.QMAX)
-    qt = throttle_q(q.reshape(-1)).reshape(w.shape)
+    qt = throttle_q(q)
     # only touch weights the throttle actually moved; keep fp32 precision elsewhere
     return jnp.where(q == qt, w, qt * scale)
 

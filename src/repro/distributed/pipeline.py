@@ -15,7 +15,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def make_pipeline_fn(stage_fn: Callable, n_stages: int, n_micro: int,
@@ -65,8 +64,8 @@ def make_pipeline_fn(stage_fn: Callable, n_stages: int, n_micro: int,
 
     def pipe(params_stacked, x_micro):
         in_specs = (jax.tree.map(lambda _: P(axis), params_stacked), P())
-        return shard_map(per_device, mesh=mesh, in_specs=in_specs,
-                         out_specs=P(), check_rep=False)(params_stacked,
-                                                         x_micro)
+        return jax.shard_map(per_device, mesh=mesh, in_specs=in_specs,
+                             out_specs=P(), check_vma=False)(params_stacked,
+                                                             x_micro)
 
     return pipe

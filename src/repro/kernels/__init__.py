@@ -4,4 +4,5 @@
 
 # Kernels: ecc_decode, ecc_encode, ecc_qmatmul (fused decode+matmul),
 # flash_attention, quant_throttle, throttle. Wrappers in ops.py; oracles in
-# ref.py. All validated via interpret=True on CPU; TPU is the target.
+# ref.py. platform.py decides the mode once: compiled on a TPU backend,
+# interpreted on CPU (where the test suite validates them).

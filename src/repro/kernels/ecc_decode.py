@@ -1,16 +1,27 @@
 """Pallas TPU kernel: in-place SEC-DED (64,57,1) decode.
 
-Streams ECC-encoded int8 weight blocks HBM->VMEM, computes the 7-bit Hsiao
-syndrome per 64-bit block with VPU popcounts, corrects single-bit errors,
-restores the non-informative sign bits, and writes decoded weights back — the
-software analogue of the paper's Fig. 2 "swizzle + standard ECC logic" path.
+Streams ECC-encoded int8 weight bytes HBM->VMEM, computes the 7-bit Hsiao
+syndrome of every 64-bit block, corrects single-bit errors, restores the
+non-informative sign bits, and writes decoded weights back — the software
+analogue of the paper's Fig. 2 "swizzle + standard ECC logic" path.
 
-Tiling: operand viewed as (nblk, 8) uint8. Block shape (BLK_N, 8): BLK_N
-blocks per VMEM tile => BLK_N*8 bytes (default 4096 blocks = 32 KiB/tile,
-well inside VMEM; bump for production). The two code tables (ROWMASK64,
-COLS64) ride along as tiny replicated operands (Pallas forbids captured
-consts). All ops are elementwise/reduction on the VPU — no MXU use, so this
-kernel is purely memory-bound (see roofline).
+Lane-dense layout. The block codec works on any ``(rows, W)`` byte tile
+whose width is a multiple of 8: an ECC block is 8 consecutive lanes, and
+byte ``c % 8`` of its block sits in lane ``c``. Every op is 32-bit VPU
+work (unsigned bytes widen to int32 on load), so the same helpers run
+inside the fused matmul and paged-attention kernels and in this
+standalone one:
+
+* each byte XORs the code columns of its set bits (an ``(8, W)`` lane
+  table, :func:`code_table`) into a partial syndrome;
+* three lane rolls XOR-reduce each aligned 8-lane group, three more
+  broadcast the block syndrome back to the group's lanes;
+* the syndrome picks the flip mask per lane, and the sign-bit restore is a
+  per-lane select.
+
+The standalone kernels take ``(..., nb, 8)`` blocks but run on the 2-D
+byte plane those blocks view (:func:`plane`), so every vreg lane carries
+data and no 8-wide minor dim is ever laid out on the device.
 """
 from __future__ import annotations
 
@@ -20,71 +31,134 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import ecc
+from . import platform
 
-DEFAULT_BLK_N = 4096
+DEFAULT_BLK_N = 32768  # blocks per grid step (256 KiB tiles)
+LANES = 128
+MAX_TILE_LANES = 2048
+_SIGN_KEEP = 0xFF ^ (1 << ecc.CHECK_BIT)
 
 
-def _decode_tile(blocks, rowmask, cols):
-    """Decode a (bn, 8) uint8 tile. Mirrors core.ecc.decode64 elementwise.
+def code_table(width: int) -> np.ndarray:
+    """(8, width) int32: row j, lane c = the 7-bit code column of bit j of
+    byte ``c % 8`` of its block."""
+    return np.tile(ecc.COLS64_BYBYTE.T.astype(np.int32), (1, width // 8))
 
-    rowmask: (7, 8) uint8 = ecc.ROWMASK64; cols: (8, 8) uint8 = COLS64_BYBYTE.
-    """
-    masked = blocks[:, None, :] & rowmask  # (bn, 7, 8)
-    pc = jax.lax.population_count(masked).astype(jnp.uint32)
-    parity = (jnp.sum(pc, axis=-1) & 1).astype(jnp.uint8)  # (bn, 7)
-    rowval = (jnp.uint8(1) << jax.lax.broadcasted_iota(jnp.uint8, (7,), 0))
-    syn = jnp.sum(parity * rowval, axis=-1).astype(jnp.uint8)  # (bn,)
 
-    syn_pc = jax.lax.population_count(syn)
-    single = (syn_pc & 1) == 1
+def _byte_pos(shape):
+    return jax.lax.broadcasted_iota(jnp.int32, shape, len(shape) - 1) & 7
+
+
+def _group_xor(t):
+    """XOR over each aligned 8-lane group, broadcast back to its lanes."""
+    w = t.shape[-1]
+    ax = t.ndim - 1
+    for s in (1, 2, 4):          # lane 8g+7 ends up holding the group XOR
+        t = t ^ pltpu.roll(t, s, ax)
+    t = jnp.where(_byte_pos(t.shape) == 7, t, 0)
+    for s in (1, 2, 4):          # copy it down to lanes 8g..8g+6
+        t = t ^ pltpu.roll(t, w - s, ax)
+    return t
+
+
+def _syndrome(x, table):
+    """x (R, W) int32 bytes -> (R, W) syndrome of each lane's block."""
+    t = jnp.zeros_like(x)
+    for j in range(8):
+        t = t ^ (((x >> j) & 1) * table[j:j + 1, :])
+    return _group_xor(t)
+
+
+def decode_lanes(x, table):
+    """Decode a lane-dense tile. x: (R, W) int32 encoded bytes (0..255);
+    table: :func:`code_table` ``(8, W)``. Returns (decoded bytes int32,
+    single, double) with each block's flags on all 8 of its lanes."""
+    syn = _syndrome(x, table)
+    single = (jax.lax.population_count(syn) & 1) == 1
     double = jnp.logical_and(syn != 0, jnp.logical_not(single))
-
-    match = (syn[:, None, None] == cols).astype(jnp.uint8)  # (bn, 8, 8)
-    bitval = (jnp.uint8(1) << jax.lax.broadcasted_iota(jnp.uint8, (8,), 0))
-    flip = jnp.sum(match * bitval, axis=-1).astype(jnp.uint8)  # (bn, 8)
-    corrected = jnp.where(single[:, None], blocks ^ flip, blocks)
-
-    # sign-bit restore: bit6 := bit7 for bytes 0..6
-    sign6 = (corrected >> 1) & np.uint8(1 << ecc.CHECK_BIT)
-    restored = (corrected & np.uint8(0xBF)) | sign6
-    keep_last = jax.lax.broadcasted_iota(jnp.int32, (8,), 0) == 7
-    dec = jnp.where(keep_last, corrected, restored)
-
-    flags = single.astype(jnp.uint8) | (double.astype(jnp.uint8) << 1)
-    return dec, flags
+    flip = jnp.zeros_like(x)
+    for j in range(8):
+        flip = flip | jnp.where(syn == table[j:j + 1, :], 1 << j, 0)
+    cor = jnp.where(single, x ^ flip, x)
+    restored = (cor & _SIGN_KEEP) | ((cor >> 1) & (1 << ecc.CHECK_BIT))
+    return jnp.where(_byte_pos(x.shape) == 7, cor, restored), single, double
 
 
-def _kernel(enc_ref, rowmask_ref, cols_ref, dec_ref, flags_ref):
-    dec, flags = _decode_tile(enc_ref[...], rowmask_ref[...], cols_ref[...])
-    dec_ref[...] = dec
-    flags_ref[...] = flags
+def encode_lanes(x, table):
+    """Encode a lane-dense tile of WOT-compliant bytes (int32 0..255): bit 6
+    of bytes 0..6 of every block is overwritten with its check bit."""
+    pos = _byte_pos(x.shape)
+    zeroed = jnp.where(pos == 7, x, x & _SIGN_KEEP)
+    syn = _syndrome(zeroed, table)
+    checks = ((syn >> pos) & 1) << ecc.CHECK_BIT
+    return zeroed | jnp.where(pos == 7, 0, checks)
 
 
-@functools.partial(jax.jit, static_argnames=("blk_n", "interpret"))
-def ecc_decode(enc: jnp.ndarray, *, blk_n: int = DEFAULT_BLK_N,
-               interpret: bool = True):
-    """(nblk, 8) uint8 -> (decoded (nblk, 8) uint8, flags (nblk,) uint8)."""
-    nblk = enc.shape[0]
-    blk_n = min(blk_n, nblk)
-    assert nblk % blk_n == 0, (nblk, blk_n)
-    grid = (nblk // blk_n,)
+def signed(x):
+    """int32 byte values 0..255 -> the int8 values they encode (as int32)."""
+    return jnp.where(x >= 128, x - 256, x)
+
+
+def plane(blocks):
+    """``(..., nb, 8)`` blocks -> ``(x, unplane, block_flags)``: ``x`` the
+    2-D byte plane the blocks view, ``unplane`` maps a plane-shaped result
+    back to ``blocks.shape`` and ``block_flags`` picks one per-lane flag per
+    block (shape ``blocks.shape[:-1]``).
+
+    Rows are the leading dims and lanes the ``nb * 8`` bytes, so the pair
+    of reshapes folds away and no array whose minor dim is 8 (padded to 128
+    lanes on TPU) is ever laid out. A flat ``(nblk, 8)`` image, or a width
+    that is not whole 128-lane vregs, is repacked as a zero-padded
+    ``(rows, 128)`` plane instead."""
+    shape = blocks.shape
+    if blocks.ndim > 2 and (shape[-2] * 8) % LANES == 0:
+        return (blocks.reshape(-1, shape[-2] * 8),
+                lambda y: y.reshape(shape),
+                lambda f: f[:, 7::8].reshape(shape[:-1]))
+    n = blocks.size
+    rows = pl.cdiv(n, LANES)
+    x = jnp.pad(blocks.reshape(-1), (0, rows * LANES - n)).reshape(rows,
+                                                                   LANES)
+    return (x, lambda y: y.reshape(-1)[:n].reshape(shape),
+            lambda f: f.reshape(-1)[7:n:8].reshape(shape[:-1]))
+
+
+def plane_call(kernel, x, blk_n: int, n_out: int):
+    """Run an elementwise lane-dense kernel over a ``(rows, W)`` uint8
+    plane in tiles of about ``blk_n`` blocks: full-width (or 2048-lane)
+    column tiles, rows in whole 32-sublane uint8 tiles."""
+    r, w = x.shape
+    tc = min(w, MAX_TILE_LANES)
+    tr = max(1, blk_n * 8 // tc)
+    tr = r if tr >= r else min(r, max(32, tr - tr % 32))
+    spec = pl.BlockSpec((tr, tc), lambda i, j: (i, j))
     return pl.pallas_call(
-        _kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((blk_n, 8), lambda i: (i, 0)),
-            pl.BlockSpec((7, 8), lambda i: (0, 0)),
-            pl.BlockSpec((8, 8), lambda i: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((blk_n, 8), lambda i: (i, 0)),
-            pl.BlockSpec((blk_n,), lambda i: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((nblk, 8), jnp.uint8),
-            jax.ShapeDtypeStruct((nblk,), jnp.uint8),
-        ],
-        interpret=interpret,
-    )(enc, jnp.asarray(ecc.ROWMASK64), jnp.asarray(ecc.COLS64_BYBYTE))
+        kernel,
+        grid=(pl.cdiv(r, tr), pl.cdiv(w, tc)),
+        in_specs=[spec, pl.BlockSpec((8, tc), lambda i, j: (0, 0))],
+        out_specs=[spec] * n_out,
+        out_shape=[jax.ShapeDtypeStruct(x.shape, jnp.uint8)] * n_out,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+        interpret=platform.interpret(),
+    )(x, jnp.asarray(code_table(tc)))
+
+
+def _kernel(enc_ref, table_ref, dec_ref, flags_ref):
+    dec, single, double = decode_lanes(enc_ref[...].astype(jnp.int32),
+                                       table_ref[...])
+    dec_ref[...] = dec.astype(jnp.uint8)
+    flags_ref[...] = (single.astype(jnp.int32) |
+                      (double.astype(jnp.int32) << 1)).astype(jnp.uint8)
+
+
+@functools.partial(jax.jit, static_argnames=("blk_n",))
+def ecc_decode(enc: jnp.ndarray, *, blk_n: int = DEFAULT_BLK_N):
+    """(..., 8) uint8 blocks -> (decoded blocks, flags (...,) uint8) with
+    flags bit 0 = single corrected, bit 1 = double detected."""
+    x, unplane, block_flags = plane(enc)
+    dec, flags = plane_call(_kernel, x, blk_n, 2)
+    return unplane(dec), block_flags(flags)

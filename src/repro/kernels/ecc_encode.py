@@ -3,7 +3,8 @@
 Runs once at deployment (and inside the protected-checkpoint writer): takes
 WOT-compliant int8 weights, computes the 7 check bits per 64-bit block and
 writes them into the non-informative bits. Memory-bound one-pass kernel,
-mirror image of `ecc_decode`.
+mirror image of `ecc_decode` and built on the same lane-dense block codec
+(:func:`ecc_decode.encode_lanes`).
 """
 from __future__ import annotations
 
@@ -11,47 +12,22 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
-from jax.experimental import pallas as pl
 
-from repro.core import ecc
+from . import ecc_decode
 
-DEFAULT_BLK_N = 4096
-_SIGN_KEEP = np.uint8(0xFF ^ (1 << ecc.CHECK_BIT))
+DEFAULT_BLK_N = ecc_decode.DEFAULT_BLK_N
 
 
-def _encode_tile(blocks, rowmask):
-    """(bn, 8) uint8 WOT weights -> encoded blocks. rowmask = ROWMASK64."""
-    keep_last = jax.lax.broadcasted_iota(jnp.int32, (8,), 0) == 7
-    zeroed = jnp.where(keep_last, blocks, blocks & _SIGN_KEEP)
-    masked = zeroed[:, None, :] & rowmask           # (bn, 7, 8)
-    pc = jax.lax.population_count(masked).astype(jnp.uint32)
-    parity = (jnp.sum(pc, axis=-1) & 1).astype(jnp.uint8)   # (bn, 7)
-    rowval = (jnp.uint8(1) << jax.lax.broadcasted_iota(jnp.uint8, (7,), 0))
-    syn = jnp.sum(parity * rowval, axis=-1).astype(jnp.uint8)
-    i = jax.lax.broadcasted_iota(jnp.uint8, (8,), 0)
-    checks = (((syn[:, None] >> i) & 1) << ecc.CHECK_BIT).astype(jnp.uint8)
-    checks = jnp.where(keep_last, jnp.uint8(0), checks)
-    return zeroed | checks
+def _kernel(w_ref, table_ref, out_ref):
+    out_ref[...] = ecc_decode.encode_lanes(
+        w_ref[...].astype(jnp.int32), table_ref[...]).astype(jnp.uint8)
 
 
-def _kernel(w_ref, rowmask_ref, out_ref):
-    out_ref[...] = _encode_tile(w_ref[...], rowmask_ref[...])
-
-
-@functools.partial(jax.jit, static_argnames=("blk_n", "interpret"))
-def ecc_encode(blocks: jnp.ndarray, *, blk_n: int = DEFAULT_BLK_N,
-               interpret: bool = True) -> jnp.ndarray:
-    """(nblk, 8) uint8 (WOT-compliant int8 bytes) -> encoded (nblk, 8)."""
-    nblk = blocks.shape[0]
-    blk_n = min(blk_n, nblk)
-    assert nblk % blk_n == 0
-    return pl.pallas_call(
-        _kernel,
-        grid=(nblk // blk_n,),
-        in_specs=[pl.BlockSpec((blk_n, 8), lambda i: (i, 0)),
-                  pl.BlockSpec((7, 8), lambda i: (0, 0))],
-        out_specs=pl.BlockSpec((blk_n, 8), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nblk, 8), jnp.uint8),
-        interpret=interpret,
-    )(blocks, jnp.asarray(ecc.ROWMASK64))
+@functools.partial(jax.jit, static_argnames=("blk_n",))
+def ecc_encode(blocks: jnp.ndarray, *,
+               blk_n: int = DEFAULT_BLK_N) -> jnp.ndarray:
+    """(..., 8) uint8 blocks (WOT-compliant int8 bytes) -> encoded, same
+    shape."""
+    x, unplane, _ = ecc_decode.plane(blocks.astype(jnp.uint8))
+    (out,) = ecc_decode.plane_call(_kernel, x, blk_n, 1)
+    return unplane(out)

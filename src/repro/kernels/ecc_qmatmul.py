@@ -6,27 +6,30 @@ to the MXU. Protection then costs zero HBM space AND zero extra HBM traffic;
 the VPU bit-twiddling overlaps with MXU matmul work on neighbouring tiles.
 
 Layout: W (K, N) int8 row-major -> 8-byte ECC blocks run along N, so any
-(BK, BN) tile with BN % 8 == 0 contains whole blocks and decodes locally.
+(BK, BN) tile with BN % 8 == 0 contains whole blocks and decodes locally
+with the lane-dense block codec (``ecc_decode.decode_lanes``).
 
 Grid (ceil(N/BN), ceil(M/BM), ceil(K/BK)) — K innermost so each output
 tile's accumulation visits are CONSECUTIVE (a TPU output block only
-persists across back-to-back grid steps; the old M-outermost order kept
-that property too, this one adds decode reuse). A VMEM scratch holds the
-decoded K-strip for the current N tile: the first M tile decodes each
-(BK, BN) weight tile into its strip slot, every later M tile reuses it —
-each weight tile is ECC-decoded ONCE per (N, K) tile instead of
-``ceil(M/BM)`` times, so the VPU decode work no longer scales with batch.
-The N grid dim is marked ``parallel`` (``dimension_semantics``) so Mosaic
-can pipeline/split independent output column strips; M and K carry the
-scratch/accumulation dependences and stay ``arbitrary``. Edge tiles are
-masked (activation columns past K zeroed, flag counts restricted to real
-blocks) so production shapes need no divisibility beyond N % 8 == 0.
-Default tiles 128x128 with full-K strips (bk=0): VMEM footprint = BM*K (a)
-+ K*BN (w enc) + ~K*BN (decoded strip) + BM*BN*4 (acc) — 16+16+16+64 KiB
-per 128-wide strip of a K=128 layer. The decoded strip is ~K*BN bytes
-REGARDLESS of ``bk`` (decode-once needs the whole K strip resident), so
-for huge-K layers shrink ``bn`` to bound VMEM; ``bk`` only sizes the a/w
-staging blocks.
+persists across back-to-back grid steps). A VMEM scratch holds the decoded
+K-strip for the current N tile: the first M tile decodes each (BK, BN)
+weight tile into its strip slot, every later M tile reuses it — each
+weight tile is ECC-decoded ONCE per (N, K) tile instead of ``ceil(M/BM)``
+times, so the VPU decode work no longer scales with batch. The decode
+walks the tile in row chunks (a ``fori_loop``) so the unrolled vector code
+stays bounded at any K. The N grid dim is marked ``parallel``; M and K
+carry the scratch/accumulation dependences and stay ``arbitrary``. Edge
+tiles are masked (activation columns past K zeroed, flag counts restricted
+to real blocks) so production shapes need no divisibility beyond
+N % 8 == 0.
+
+Tiles are clamped to what the TPU compiler accepts (the last two block
+dims divisible by (8, 128), or equal to the array dims): BN a multiple of
+128 or all of N, BK a multiple of 128 or all of K, BM a multiple of 32 or
+all of M. Default 128-wide N strips with full-K tiles (bk=0). The decoded
+strip is K*BN bytes (int8 paths) or K*BN*itemsize (float path, which
+stores the dequantized strip) REGARDLESS of ``bk`` — decode-once needs the
+whole K strip resident — so for huge-K layers shrink ``bn`` to bound VMEM.
 
 Three activation paths share the kernel:
 
@@ -73,92 +76,159 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core import ecc
-from . import ecc_decode
+from . import ecc_decode, platform
 
 # float-path ABFT tolerance: checksum reordering noise is ~K * eps(f32)
 # relative to the |a|·|w| scale (~1e-5 at K=128); 1e-4 leaves a decade of
 # margin while still firing on any exponent-scale corruption.
-ABFT_RTOL = 1e-4
 ABFT_ATOL = 1e-6
+ABFT_RTOL = 1e-4
+
+LANES = 128
+# count lanes of the per-N-strip counter row
+_SINGLE, _DOUBLE, _COLS = 0, 1, 2
 
 
-def _kernel(*refs, dims, path, has_bias, has_clamp, with_abft, fault_bits):
+def _legal(t: int, full: int, align: int) -> int:
+    """Clamp a requested tile to one the TPU compiler accepts: the whole
+    dim, or a multiple of ``align`` below it."""
+    if t <= 0 or t >= full:
+        return full
+    return min(full, max(align, t - t % align))
+
+
+def _row_chunk(bk: int) -> int:
+    """Rows decoded per loop step: the largest uint8-tile-aligned chunk
+    dividing ``bk`` (bounded unrolled vector code at any K)."""
+    for rc in (512, 256, 128, 64, 32):
+        if bk % rc == 0:
+            return rc
+    return bk
+
+
+def _digits(x, axis):
+    """Base-128 int8 digits of int32 ``x`` (|x| < 2**28), all of whose
+    slices along ``axis`` are equal: digit d lands at index d of ``axis``
+    (least significant first), zeros past index 3."""
+    idx = jax.lax.broadcasted_iota(jnp.int32, x.shape, axis)
+    out = jnp.zeros_like(x)
+    for d in range(4):
+        dig = (x >> (7 * d)) if d == 3 else (x >> (7 * d)) & 127
+        out = jnp.where(idx == d, dig, out)
+    return out.astype(jnp.int8)
+
+
+def _exact_checksums(a, w):
+    """The ABFT pair for int8 ``a (BM, BK)`` and ``w (BK, BN)`` in int32
+    modular arithmetic: ``a @ rowsum(w)`` (BM, 1) and ``colsum(a) @ w``
+    (1, BN). The sums outgrow int8, so each is split into base-128 int8
+    digits and every product runs as an int8 MXU matmul (the MXU has no
+    int32 operands); the digit dots recombine exactly."""
+    i32 = jnp.int32
+    dn = (((1,), (0,)), ((), ()))
+    bm, bk = a.shape
+    bn = w.shape[1]
+    rowsum = jax.lax.dot_general(w, jnp.ones((bn, LANES), jnp.int8), dn,
+                                 preferred_element_type=i32)   # (BK, 128)
+    parts = jax.lax.dot_general(a, _digits(rowsum, 1), dn,
+                                preferred_element_type=i32)    # (BM, 128)
+    lane = jax.lax.broadcasted_iota(i32, (1, LANES), 1)
+    rs_ref = jnp.sum(parts * jnp.where(lane < 4, 1 << (7 * lane), 0),
+                     axis=1, keepdims=True)
+    colsum = jax.lax.dot_general(jnp.ones((8, bm), jnp.int8), a, dn,
+                                 preferred_element_type=i32)   # (8, BK)
+    parts = jax.lax.dot_general(_digits(colsum, 0), w, dn,
+                                preferred_element_type=i32)    # (8, BN)
+    row = jax.lax.broadcasted_iota(i32, (8, 1), 0)
+    cs_ref = jnp.sum(parts * jnp.where(row < 4, 1 << (7 * row), 0),
+                     axis=0, keepdims=True)
+    return rs_ref, cs_ref
+
+
+def _kernel(*refs, dims, path, has_bias, has_clamp, with_abft, fault_bits,
+            rc):
     m, n, k = dims
     track = with_abft or has_clamp
     it = iter(refs)
-    a_ref, w_ref, scale_ref = next(it), next(it), next(it)
+    a_ref, w_ref, table_ref, scale_ref = next(it), next(it), next(it), next(it)
     ascale_ref = next(it) if path == "requant" else None
     bias_ref = next(it) if has_bias else None
     clamp_ref = next(it) if has_clamp else None
-    rowmask_ref, cols_ref = next(it), next(it)
-    out_ref, flags_ref = next(it), next(it)
+    out_ref, counts_ref = next(it), next(it)
     abft_rows_ref = next(it) if track else None
-    abft_cols_ref = next(it) if track else None
     wdec_ref = next(it)
     j, i, kk = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     bm, bk = a_ref.shape
+    bn = w_ref.shape[1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
+
+    def bump(slot, count):
+        """Add a (1, 1) count into one lane of this N strip's counter row."""
+        counts_ref[0] += jnp.where(lane == slot, count, 0)
 
     @pl.when(jnp.logical_and(i == 0, kk == 0))
-    def _init_flags():
-        flags_ref[...] = jnp.zeros_like(flags_ref)
+    def _init_counts():
+        counts_ref[...] = jnp.zeros_like(counts_ref)
 
     if track:
-        # per-(j, i) row counters accumulate over kk; the column-check
-        # counter is per j like the decode flags (j outermost -> both
-        # revisit patterns are consecutive, TPU-legal accumulation).
+        # per-(j, i) row counters accumulate over kk (j outermost -> the
+        # revisit pattern is consecutive, TPU-legal accumulation).
         @pl.when(kk == 0)
         def _init_abft_rows():
             abft_rows_ref[...] = jnp.zeros_like(abft_rows_ref)
 
-        @pl.when(jnp.logical_and(i == 0, kk == 0))
-        def _init_abft_cols():
-            abft_cols_ref[...] = jnp.zeros_like(abft_cols_ref)
+    col = j * bn + jax.lax.broadcasted_iota(jnp.int32, (1, bn), 1)
+    colv = col < n
 
     # decode ONCE per (N, K) tile — the first M tile fills this K-strip slot
     # of the VMEM scratch, every later M tile reuses it. Flag counting lives
-    # inside the same predicate (each real block counted exactly once,
-    # M-grid independent by construction: re-decoding would multiply the
-    # counts by the M tile count).
+    # inside the same predicate (each real block counted exactly once, on
+    # the last lane of the block).
     @pl.when(i == 0)
     def _decode():
-        w_enc = w_ref[...]  # (BK, BN) uint8, ECC-encoded
-        bk2, bn = w_enc.shape
-        dec, fl = ecc_decode._decode_tile(
-            w_enc.reshape(bk2 * bn // 8, 8), rowmask_ref[...], cols_ref[...])
-        wdec_ref[pl.ds(kk * bk2, bk2), :] = jax.lax.bitcast_convert_type(
-            dec.reshape(bk2, bn), jnp.int8)
-        blk = fl.reshape(bk2, bn // 8)
-        rowv = (kk * bk2 +
-                jax.lax.broadcasted_iota(jnp.int32, blk.shape, 0)) < k
-        colv = (j * bn // 8 +
-                jax.lax.broadcasted_iota(jnp.int32, blk.shape, 1)) < n // 8
-        valid = jnp.logical_and(rowv, colv)
-        single = jnp.logical_and((blk & 1) == 1, valid)
-        double = jnp.logical_and((blk & 2) == 2, valid)
-        flags_ref[0, 0] += jnp.sum(single.astype(jnp.int32))
-        flags_ref[0, 1] += jnp.sum(double.astype(jnp.int32))
+        table = table_ref[...]
+        block_lane = jnp.logical_and(colv, (col & 7) == 7)
+
+        def chunk(r, carry):
+            r0 = pl.multiple_of(r * rc, rc)
+            x = w_ref[pl.ds(r0, rc), :].astype(jnp.int32)
+            dec, single, double = ecc_decode.decode_lanes(x, table)
+            q = ecc_decode.signed(dec)
+            if path == "float":   # the strip holds the dequantized weights
+                w = (q.astype(jnp.float32) * scale_ref[0, 0]
+                     ).astype(wdec_ref.dtype)
+            else:
+                w = q.astype(jnp.int8)
+            wdec_ref[pl.ds(pl.multiple_of(kk * bk + r0, rc), rc), :] = w
+            row = kk * bk + r0 + jax.lax.broadcasted_iota(jnp.int32,
+                                                          (rc, 1), 0)
+            valid = jnp.logical_and(row < k, block_lane)
+            s_cnt, d_cnt = carry
+            s_cnt += jnp.sum(jnp.logical_and(single, valid).astype(
+                jnp.int32), axis=0, keepdims=True)
+            d_cnt += jnp.sum(jnp.logical_and(double, valid).astype(
+                jnp.int32), axis=0, keepdims=True)
+            return s_cnt, d_cnt
+
+        zero = jnp.zeros((1, bn), jnp.int32)
+        s_cnt, d_cnt = jax.lax.fori_loop(0, bk // rc, chunk, (zero, zero))
+        bump(_SINGLE, jnp.sum(s_cnt, axis=1, keepdims=True))
+        bump(_DOUBLE, jnp.sum(d_cnt, axis=1, keepdims=True))
 
     a = a_ref[...]  # (BM, BK)
-    # mask activation columns past K so edge tiles contribute nothing
-    kcol = kk * bk + jax.lax.broadcasted_iota(jnp.int32, (bm, bk), 1)
-    a = jnp.where(kcol < k, a, jnp.zeros_like(a))
-    if with_abft:
-        # also zero activation rows past M: decoded weight bytes are always
-        # finite int8 so garbage columns cancel in the checksum identities,
-        # but float-path activation padding could be NaN and would poison
-        # the column check. Valid output rows are unaffected.
-        mrow = (i * bm +
-                jax.lax.broadcasted_iota(jnp.int32, (bm, bk), 0)) < m
-        a = jnp.where(mrow, a, jnp.zeros_like(a))
-    w_q = wdec_ref[pl.ds(kk * bk, bk), :]
+    if k % bk:  # mask activation columns past K so edge tiles contribute 0
+        kcol = kk * bk + jax.lax.broadcasted_iota(jnp.int32, (bm, bk), 1)
+        a = jnp.where(kcol < k, a, jnp.zeros_like(a))
+    if with_abft and m % bm:
+        # also zero activation rows past M: decoded weights are always
+        # finite so garbage columns cancel in the checksum identities, but
+        # float-path activation padding could be NaN and would poison the
+        # column check. Valid output rows are unaffected.
+        mrow = i * bm + jax.lax.broadcasted_iota(jnp.int32, (bm, bk), 0)
+        a = jnp.where(mrow < m, a, jnp.zeros_like(a))
+    w = wdec_ref[pl.ds(pl.multiple_of(kk * bk, bk), bk), :]
     dn = (((1,), (0,)), ((), ()))
-
-    rowv = (i * bm +
-            jax.lax.broadcasted_iota(jnp.int32, (bm, 1), 0)) < m
-    bn_cur = out_ref.shape[-1]
-    colv = (j * bn_cur +
-            jax.lax.broadcasted_iota(jnp.int32, (1, bn_cur), 1)) < n
+    rowv = (i * bm + jax.lax.broadcasted_iota(jnp.int32, (bm, 1), 0)) < m
 
     def _flip(partial):
         """XOR fault_bits into element (0, 0) of the first tile's partial
@@ -180,17 +250,18 @@ def _kernel(*refs, dims, path, has_bias, has_clamp, with_abft, fault_bits):
         row sums vs a @ rowsum(w), column sums vs colsum(a) @ w."""
         dt = partial.dtype
         rs_acc = jnp.sum(partial, axis=1, keepdims=True)              # (BM,1)
-        rs_ref = jax.lax.dot_general(
-            a_chk, jnp.sum(w_chk, axis=1, keepdims=True), dn,
-            preferred_element_type=dt)
         cs_acc = jnp.sum(partial, axis=0, keepdims=True)              # (1,BN)
-        cs_ref = jax.lax.dot_general(
-            jnp.sum(a_chk, axis=0, keepdims=True), w_chk, dn,
-            preferred_element_type=dt)
         if exact:
+            rs_ref, cs_ref = _exact_checksums(a_chk, w_chk)
             row_bad = rs_acc != rs_ref
             col_bad = cs_acc != cs_ref
         else:
+            rs_ref = jax.lax.dot_general(
+                a_chk, jnp.sum(w_chk, axis=1, keepdims=True), dn,
+                preferred_element_type=dt)
+            cs_ref = jax.lax.dot_general(
+                jnp.sum(a_chk, axis=0, keepdims=True), w_chk, dn,
+                preferred_element_type=dt)
             a_abs, w_abs = jnp.abs(a_chk), jnp.abs(w_chk)
             rs_sc = jax.lax.dot_general(
                 a_abs, jnp.sum(w_abs, axis=1, keepdims=True), dn,
@@ -202,8 +273,8 @@ def _kernel(*refs, dims, path, has_bias, has_clamp, with_abft, fault_bits):
             col_bad = jnp.abs(cs_acc - cs_ref) > ABFT_ATOL + ABFT_RTOL * cs_sc
         abft_rows_ref[0, :, 0:1] += jnp.logical_and(
             row_bad, rowv).astype(jnp.int32)
-        abft_cols_ref[0, 0] += jnp.sum(
-            jnp.logical_and(col_bad, colv).astype(jnp.int32))
+        bump(_COLS, jnp.sum(jnp.logical_and(col_bad, colv).astype(jnp.int32),
+                            axis=1, keepdims=True))
 
     def _clamp(res):
         """Geissler-style range supervision: clip the f32 epilogue output
@@ -216,7 +287,6 @@ def _kernel(*refs, dims, path, has_bias, has_clamp, with_abft, fault_bits):
         return jnp.clip(res, -c, c)
 
     if path == "float":
-        w = (w_q.astype(jnp.float32) * scale_ref[0, 0]).astype(a.dtype)
         partial = jax.lax.dot_general(
             a, w, dimension_numbers=dn, preferred_element_type=jnp.float32)
         if fault_bits:
@@ -236,12 +306,11 @@ def _kernel(*refs, dims, path, has_bias, has_clamp, with_abft, fault_bits):
                 out_ref[...] = _clamp(out_ref[...])
     elif path == "int8":
         partial = jax.lax.dot_general(
-            a, w_q, dimension_numbers=dn, preferred_element_type=jnp.int32)
+            a, w, dimension_numbers=dn, preferred_element_type=jnp.int32)
         if fault_bits:
             partial = _flip(partial)
         if with_abft:
-            _abft(partial, a.astype(jnp.int32), w_q.astype(jnp.int32),
-                  exact=True)
+            _abft(partial, a, w, exact=True)
 
         @pl.when(kk == 0)
         def _init():
@@ -250,12 +319,11 @@ def _kernel(*refs, dims, path, has_bias, has_clamp, with_abft, fault_bits):
         out_ref[...] += partial
     else:  # requant epilogue: full-K tile (single kk), exact int32 acc
         acc = jax.lax.dot_general(
-            a, w_q, dimension_numbers=dn, preferred_element_type=jnp.int32)
+            a, w, dimension_numbers=dn, preferred_element_type=jnp.int32)
         if fault_bits:
             acc = _flip(acc)
         if with_abft:
-            _abft(acc, a.astype(jnp.int32), w_q.astype(jnp.int32),
-                  exact=True)
+            _abft(acc, a, w, exact=True)
         if has_bias:
             acc = acc + bias_ref[...]  # (1, BN) int32, accumulator scale
         s = ascale_ref[...] * scale_ref[0, 0]  # (BM, 1) f32
@@ -265,13 +333,17 @@ def _kernel(*refs, dims, path, has_bias, has_clamp, with_abft, fault_bits):
         out_ref[...] = res.astype(out_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret",
+def _smem():
+    return pl.BlockSpec(memory_space=pltpu.SMEM)
+
+
+@functools.partial(jax.jit, static_argnames=("bm", "bn", "bk",
                                              "with_flags", "out_dtype",
                                              "with_abft", "fault_bits"))
 def ecc_qmatmul(a: jnp.ndarray, w_enc: jnp.ndarray, w_scale=None, *,
                 a_scale=None, bias=None, out_dtype=None,
                 bm: int = 128, bn: int = 128, bk: int = 0,
-                interpret: bool = True, with_flags: bool = False,
+                with_flags: bool = False,
                 with_abft: bool = False, clamp=None, fault_bits: int = 0):
     """``a (M,K) @ decode(w_enc (K,N) uint8)``, decode fused into the matmul.
 
@@ -303,7 +375,8 @@ def ecc_qmatmul(a: jnp.ndarray, w_enc: jnp.ndarray, w_scale=None, *,
     fault_bits   -> nonzero XORs the pattern into accumulator element
                     (0, 0) of the first tile (deterministic injected SDC).
 
-    Tiles need not divide (M, N, K) — edge tiles are masked. N % 8 == 0 is
+    Tiles need not divide (M, N, K) — edge tiles are masked — and are
+    clamped to TPU-legal sizes (module docstring). N % 8 == 0 is
     structural (ECC blocks run along N). The first M tile decodes each
     weight tile into a K-strip VMEM scratch that later M tiles reuse, so
     per-call decode work is ceil(N/BN) * ceil(K/BK) tiles — independent of
@@ -330,10 +403,9 @@ def ecc_qmatmul(a: jnp.ndarray, w_enc: jnp.ndarray, w_scale=None, *,
         raise ValueError("clamp guards the f32 epilogue output; the raw "
                          "int8-accumulator path has none")
     track = with_abft or has_clamp
-    if bk == 0 or requant:
-        bk = k  # full-K tile: one dot per output tile, XLA-identical order
-    bm, bn, bk = min(bm, m), min(bn, n), min(bk, k)
-    bn = max(8, bn - bn % 8)  # whole ECC blocks per tile
+    if requant:
+        bk = 0  # full-K tile: one dot per output tile, XLA-identical order
+    bm, bn, bk = _legal(bm, m, 32), _legal(bn, n, LANES), _legal(bk, k, LANES)
     grid = (pl.cdiv(n, bn), pl.cdiv(m, bm), pl.cdiv(k, bk))
     scale = jnp.asarray(w_scale if w_scale is not None else 1.0,
                         jnp.float32).reshape(1, 1)
@@ -345,13 +417,15 @@ def ecc_qmatmul(a: jnp.ndarray, w_enc: jnp.ndarray, w_scale=None, *,
         out_dt = jnp.dtype(out_dtype) if out_dtype is not None else jnp.bfloat16
     kern = functools.partial(_kernel, dims=(m, n, k), path=path,
                              has_bias=bias is not None, has_clamp=has_clamp,
-                             with_abft=with_abft, fault_bits=int(fault_bits))
+                             with_abft=with_abft, fault_bits=int(fault_bits),
+                             rc=_row_chunk(bk))
 
-    inputs = [a, w_enc, scale]
+    inputs = [a, w_enc, jnp.asarray(ecc_decode.code_table(bn)), scale]
     in_specs = [
         pl.BlockSpec((bm, bk), lambda j, i, kk: (i, kk)),
         pl.BlockSpec((bk, bn), lambda j, i, kk: (kk, j)),
-        pl.BlockSpec((1, 1), lambda j, i, kk: (0, 0)),
+        pl.BlockSpec((8, bn), lambda j, i, kk: (0, 0)),
+        _smem(),
     ]
     if requant:
         ascale = jnp.broadcast_to(
@@ -365,30 +439,20 @@ def ecc_qmatmul(a: jnp.ndarray, w_enc: jnp.ndarray, w_scale=None, *,
             in_specs.append(pl.BlockSpec((1, bn), lambda j, i, kk: (0, j)))
     if has_clamp:
         inputs.append(jnp.asarray(clamp, jnp.float32).reshape(1, 1))
-        in_specs.append(pl.BlockSpec((1, 1), lambda j, i, kk: (0, 0)))
-    inputs += [jnp.asarray(ecc.ROWMASK64), jnp.asarray(ecc.COLS64_BYBYTE)]
-    in_specs += [
-        pl.BlockSpec((7, 8), lambda j, i, kk: (0, 0)),
-        pl.BlockSpec((8, 8), lambda j, i, kk: (0, 0)),
-    ]
+        in_specs.append(_smem())
 
     out_specs = [
         pl.BlockSpec((bm, bn), lambda j, i, kk: (i, j)),
-        pl.BlockSpec((1, 2), lambda j, i, kk: (j, 0)),
+        pl.BlockSpec((1, 1, LANES), lambda j, i, kk: (j, 0, 0)),
     ]
     out_shape = [
         jax.ShapeDtypeStruct((m, n), out_dt),
-        jax.ShapeDtypeStruct((grid[0], 2), jnp.int32),
+        jax.ShapeDtypeStruct((grid[0], 1, LANES), jnp.int32),
     ]
     if track:
-        out_specs += [
-            pl.BlockSpec((1, bm, 2), lambda j, i, kk: (j, i, 0)),
-            pl.BlockSpec((1, 2), lambda j, i, kk: (j, 0)),
-        ]
-        out_shape += [
-            jax.ShapeDtypeStruct((grid[0], m, 2), jnp.int32),
-            jax.ShapeDtypeStruct((grid[0], 2), jnp.int32),
-        ]
+        out_specs.append(pl.BlockSpec((1, bm, 2), lambda j, i, kk: (j, i, 0)))
+        out_shape.append(jax.ShapeDtypeStruct((grid[0], m, 2), jnp.int32))
+    strip_dt = a.dtype if path == "float" else jnp.int8
 
     res = pl.pallas_call(
         kern,
@@ -396,17 +460,17 @@ def ecc_qmatmul(a: jnp.ndarray, w_enc: jnp.ndarray, w_scale=None, *,
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((grid[2] * bk, bn), jnp.int8)],
-        compiler_params=pltpu.TPUCompilerParams(
+        scratch_shapes=[pltpu.VMEM((grid[2] * bk, bn), strip_dt)],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
-        interpret=interpret,
+        interpret=platform.interpret(),
     )(*inputs)
-    out, flags = res[0], res[1]
+    out, counts = res[0], res[1].sum(axis=(0, 1))
     outs = (out,)
     if with_flags:
-        outs += (flags.sum(axis=0),)
+        outs += (counts[_SINGLE:_DOUBLE + 1],)
     if track:
         # per-row (mismatch, clamp-hit) counts summed over N strips, plus
         # the column-check mismatch total (not row-attributable).
-        outs += ((res[2].sum(axis=0), res[3].sum(axis=0)[0]),)
+        outs += ((res[2].sum(axis=0), counts[_COLS]),)
     return outs if len(outs) > 1 else out

@@ -22,6 +22,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from . import platform
+
 NEG_INF = -1e30
 
 
@@ -66,9 +68,8 @@ def _norm_kernel(o_ref, l_ref, out_ref):
         out_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("bq", "bk", "interpret"))
-def flash_attention(q, k, v, *, bq: int = 128, bk: int = 128,
-                    interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("bq", "bk"))
+def flash_attention(q, k, v, *, bq: int = 128, bk: int = 128):
     """Causal flash attention. q,k,v: (B, H, S, D) -> (B, H, S, D).
 
     GQA callers broadcast KV heads beforehand (or reshape to grouped form).
@@ -102,7 +103,7 @@ def flash_attention(q, k, v, *, bq: int = 128, bk: int = 128,
             jax.ShapeDtypeStruct((b * h, s), jnp.float32),
             jax.ShapeDtypeStruct((b * h, s), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=platform.interpret(),
     )(qf, kf, vf)
     out = pl.pallas_call(
         _norm_kernel,
@@ -111,6 +112,6 @@ def flash_attention(q, k, v, *, bq: int = 128, bk: int = 128,
                   pl.BlockSpec((1, bq), lambda g, i: (g, i))],
         out_specs=pl.BlockSpec((1, bq, d), lambda g, i: (g, i, 0)),
         out_shape=jax.ShapeDtypeStruct((b * h, s, d), dtype),
-        interpret=interpret,
+        interpret=platform.interpret(),
     )(o, l)
     return out.reshape(b, h, s, d)

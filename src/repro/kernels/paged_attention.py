@@ -39,8 +39,11 @@ over the SAME encoded strips — instead of a bit-equality check. Flag
 counts (integer, decode-exact) still match the reference exactly.
 
 The page-table gather itself (pool -> (B, S, ...) strips) stays in XLA
-before the ``pallas_call``: gathers are layout transforms XLA schedules
-well, while the kernels own everything that must not leave VMEM decoded.
+before the ``pallas_call``, as does the transpose to (B, KV, S, hd) that
+makes each grid cell's strip a TPU-legal block: gathers are layout
+transforms XLA schedules well, while the kernels own everything that must
+not leave VMEM decoded. Blocks decode with the lane-dense codec shared with
+the fused matmul (``ecc_decode.decode_lanes``).
 Flags (corrected, DUE) are masked to valid (``<= pos``) tokens inside the
 kernel, summed per (batch, kv-head) cell, and reduced outside — per
 batch row (``per_slot=True``, for per-request fault attribution) or to
@@ -57,8 +60,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core import ecc
-from . import ecc_decode
+from . import ecc_decode, platform
 
 KV_SCHEMES = ("faulty", "parity-zero", "in-place")
 
@@ -68,82 +70,160 @@ KV_SCHEMES = ("faulty", "parity-zero", "in-place")
 VMEM_BUDGET_BYTES = 16 * 2 ** 20
 
 
-def _decode_strip(enc, ch, valid_col, rowmask, cols, *, scheme):
+def _count(hit, valid_col):
+    """(s, W) bool hits over valid (s, 1) tokens -> (1, 1) int32 count."""
+    n = jnp.where(valid_col, hit.astype(jnp.int32), 0)
+    return jnp.sum(jnp.sum(n, axis=0, keepdims=True), axis=1, keepdims=True)
+
+
+def _decode_strip(enc, ch, valid_col, table, *, scheme):
     """Decode one (s, hd) uint8 encoded strip in-kernel.
 
-    -> (int8 (s, hd), corrected, due) — scalar flag counts already masked
-    to ``valid_col`` (s, 1) tokens. Shared by the strip and chunked
-    kernels so both observe identical per-token fault accounting.
+    -> (int8 values as int32 (s, hd), corrected (1, 1), due (1, 1)) — flag
+    counts already masked to ``valid_col`` (s, 1) tokens. Shared by the
+    strip and chunked kernels so both observe identical per-token fault
+    accounting.
     """
-    s, hd = enc.shape
+    x = enc.astype(jnp.int32)
+    zero = jnp.zeros((1, 1), jnp.int32)
     if scheme == "faulty":
-        z = jnp.zeros((), jnp.int32)
-        return jax.lax.bitcast_convert_type(enc, jnp.int8), z, z
+        return ecc_decode.signed(x), zero, zero
+    s, hd = x.shape
+    lane = jax.lax.broadcasted_iota(jnp.int32, (s, hd), 1)
     if scheme == "parity-zero":
-        # constant-free restatement of ecc.decode_parity8 (whose packed
-        # weight tables would be captured consts inside a Pallas kernel):
-        # byte j's stored parity is bit (j % 8) of check byte j // 8.
-        par = (jax.lax.population_count(enc) & 1).astype(jnp.uint8)
-        sh = (jax.lax.broadcasted_iota(jnp.int32, (s, hd), 1) % 8
-              ).astype(jnp.uint8)
-        stored = (jnp.repeat(ch, 8, axis=1) >> sh) & jnp.uint8(1)
-        bad = par != stored
-        data = jnp.where(bad, jnp.uint8(0), enc)
-        cor = jnp.sum(jnp.where(valid_col, bad.astype(jnp.int32), 0))
-        return (jax.lax.bitcast_convert_type(data, jnp.int8), cor,
-                jnp.zeros((), jnp.int32))
-    dcd, fl = ecc_decode._decode_tile(enc.reshape(s * hd // 8, 8),
-                                      rowmask, cols)
-    fl = fl.reshape(s, hd // 8)
-    cor = jnp.sum(jnp.where(valid_col, (fl & 1).astype(jnp.int32), 0))
-    due = jnp.sum(jnp.where(valid_col, ((fl >> 1) & 1).astype(jnp.int32),
-                            0))
-    return jax.lax.bitcast_convert_type(dcd.reshape(s, hd), jnp.int8), \
-        cor, due
+        # restatement of ecc.decode_parity8: byte j's stored parity is bit
+        # (j % 8) of check byte j // 8. An exact 0/1 matmul spreads each
+        # check byte over its 8 lanes.
+        nb = ch.shape[-1]
+        spread = (jax.lax.broadcasted_iota(jnp.int32, (nb, hd), 1) // 8 ==
+                  jax.lax.broadcasted_iota(jnp.int32, (nb, hd), 0))
+        stored = jax.lax.dot_general(
+            ch.astype(jnp.int32).astype(jnp.float32),
+            spread.astype(jnp.float32), (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32).astype(jnp.int32)
+        bad = (jax.lax.population_count(x) & 1) != ((stored >> (lane & 7)) & 1)
+        return (ecc_decode.signed(jnp.where(bad, 0, x)),
+                _count(bad, valid_col), zero)
+    dec, single, double = ecc_decode.decode_lanes(x, table)
+    block_lane = (lane & 7) == 7      # count each block once
+    return (ecc_decode.signed(dec),
+            _count(jnp.logical_and(single, block_lane), valid_col),
+            _count(jnp.logical_and(double, block_lane), valid_col))
 
 
-def _kernel(q_ref, ke_ref, kch_ref, ksc_ref, ve_ref, vch_ref, vsc_ref,
-            pos_ref, rowmask_ref, cols_ref, o_ref, flags_ref, *, scheme, s):
-    qb = q_ref[0, 0]                                   # (rep, hd)
+def _flag_row(cor, due):
+    """(1, 1) counts -> the (1, 128) lane row a grid cell writes."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, 128), 1)
+    return jnp.where(lane == 0, cor, jnp.where(lane == 1, due, 0))
+
+
+def _unpack(refs, has_checks):
+    """Positional kernel refs -> dict (the check planes ride only with the
+    parity-zero scheme)."""
+    it = iter(refs)
+    names = ["q", "ke"] + (["kch"] if has_checks else []) + \
+        ["ksc", "ve"] + (["vch"] if has_checks else []) + \
+        ["vsc", "pos", "table", "o", "flags"]
+    r = {name: next(it) for name in names}
+    r["scratch"] = list(it)
+    return r
+
+
+def _strips(r, valid_col, scheme):
+    """Decode this grid cell's K and V strips -> (kq, vq, cor, due)."""
+    table = r["table"][...]
+    ch = lambda name: r[name][0, 0] if name in r else None
+    kq, kcor, kdue = _decode_strip(r["ke"][0, 0], ch("kch"), valid_col,
+                                   table, scheme=scheme)
+    vq, vcor, vdue = _decode_strip(r["ve"][0, 0], ch("vch"), valid_col,
+                                   table, scheme=scheme)
+    return kq, vq, kcor + vcor, kdue + vdue
+
+
+def _kernel(*refs, scheme, s, has_checks):
+    r = _unpack(refs, has_checks)
+    qb = r["q"][0, 0]                                  # (rep, hd)
     hd = qb.shape[-1]
-    pos = pos_ref[0, 0]
+    pos = r["pos"][pl.program_id(0)]
     # 2-D iotas throughout (Mosaic rejects rank-1 iota outside interpret)
-    tok = jax.lax.broadcasted_iota(jnp.int32, (s, 1), 0)
-    valid_col = tok <= pos                             # (s, 1)
-
-    kq, kcor, kdue = _decode_strip(ke_ref[0, :, 0, :], kch_ref[0, :, 0, :],
-                                   valid_col, rowmask_ref[...],
-                                   cols_ref[...], scheme=scheme)
-    vq, vcor, vdue = _decode_strip(ve_ref[0, :, 0, :], vch_ref[0, :, 0, :],
-                                   valid_col, rowmask_ref[...],
-                                   cols_ref[...], scheme=scheme)
+    valid_col = jax.lax.broadcasted_iota(jnp.int32, (s, 1), 0) <= pos
+    valid_row = jax.lax.broadcasted_iota(jnp.int32, (1, s), 1) <= pos
+    kq, vq, cor, due = _strips(r, valid_col, scheme)
     cdt = qb.dtype
-    kf = (kq.astype(jnp.float32) * ksc_ref[0][:, None]).astype(cdt)  # (s, hd)
-    vf = (vq.astype(jnp.float32) * vsc_ref[0][:, None]).astype(cdt)
-    # score path mirrors layers.decode_attention op for op (bit-identity)
+    kf = (kq.astype(jnp.float32) * r["ksc"][0]).astype(cdt)   # (s, hd)
+    vf = (vq.astype(jnp.float32) * r["vsc"][0]).astype(cdt)
+    # score path mirrors layers.decode_attention as XLA compiles it (bit-
+    # identity): the bf16 score dot feeds an f32 cast, which XLA folds into
+    # an f32-accumulated dot; the PV dot rounds its f32 sum once to bf16
     sc = jax.lax.dot_general(qb, kf,
-                             dimension_numbers=(((1,), (1,)), ((), ())))
-    sc = sc.astype(jnp.float32) * (1.0 / np.sqrt(hd))  # (rep, s)
-    sc = jnp.where(valid_col.reshape(1, s), sc, -1e30)
+                             dimension_numbers=(((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+    sc = sc * (1.0 / np.sqrt(hd))                      # (rep, s)
+    sc = jnp.where(valid_row, sc, -1e30)
     pr = jax.nn.softmax(sc, axis=-1).astype(cdt)
-    o_ref[0, 0] = jax.lax.dot_general(
-        pr, vf, dimension_numbers=(((1,), (0,)), ((), ()))).astype(o_ref.dtype)
-    flags_ref[0, 0] = jnp.stack([kcor + vcor, kdue + vdue])
+    r["o"][0, 0] = jax.lax.dot_general(
+        pr, vf, dimension_numbers=(((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32).astype(r["o"].dtype)
+    r["flags"][0, 0] = _flag_row(cor, due)
 
 
 def _reduce_flags(flags, per_slot: bool):
-    """(b, kv, 2) in-grid flag cells -> (2, b) per-slot rows or (2,)
+    """(b, kv, 1, 128) in-grid flag rows -> (2, b) per-slot rows or (2,)
     batch totals."""
+    flags = flags[:, :, 0, :2]
     if per_slot:
         return flags.sum(axis=1).T                     # (2, b)
     return flags.sum(axis=(0, 1))                      # (2,)
 
 
-@functools.partial(jax.jit, static_argnames=("scheme", "interpret",
-                                             "per_slot"))
+def _call(kernel, q, ke, kch, ksc, ve, vch, vsc, pos, *, scheme, sblk,
+          seq_block, grid, scratch=(), semantics):
+    """Shared pallas_call plumbing of both kernels. Strips are gathered in
+    XLA as (B, S, KV, x) and handed over as (B, KV, S, x) so each grid
+    cell's (S, hd) strip is a block whose last two dims are whole array
+    dims; scales ride as (B, S, 1) columns, positions in SMEM."""
+    b, h, _, hd = q.shape
+    kv = ke.shape[2]
+    rep = h // kv
+    has_checks = scheme == "parity-zero"
+    if has_checks and kch is None:
+        kch = jnp.zeros((*ke.shape[:3], hd // 8), jnp.uint8)
+        vch = jnp.zeros((*ve.shape[:3], hd // 8), jnp.uint8)
+    heads = lambda bi, g, *c: (bi, g, 0, 0)
+    strip = lambda bi, g, *c: (bi, g, seq_block(*c), 0)
+    column = lambda bi, g, *c: (bi, seq_block(*c), 0)
+    to_kv = lambda a: a.transpose(0, 2, 1, 3)
+    ops, specs = [q[:, :, 0, :].reshape(b, kv, rep, hd)], \
+        [pl.BlockSpec((1, 1, rep, hd), heads)]
+    for enc, ch, sc in ((ke, kch, ksc), (ve, vch, vsc)):
+        ops.append(to_kv(enc))
+        specs.append(pl.BlockSpec((1, 1, sblk, hd), strip))
+        if has_checks:
+            ops.append(to_kv(ch))
+            specs.append(pl.BlockSpec((1, 1, sblk, hd // 8), strip))
+        ops.append(sc.reshape(*sc.shape, 1))
+        specs.append(pl.BlockSpec((1, sblk, 1), column))
+    ops += [pos.reshape(b).astype(jnp.int32),
+            jnp.asarray(ecc_decode.code_table(hd))]
+    specs += [pl.BlockSpec(memory_space=pltpu.SMEM),
+              pl.BlockSpec((8, hd), lambda *_: (0, 0))]
+    return pl.pallas_call(
+        functools.partial(kernel, scheme=scheme, has_checks=has_checks),
+        grid=grid,
+        in_specs=specs,
+        out_specs=[pl.BlockSpec((1, 1, rep, hd), heads),
+                   pl.BlockSpec((1, 1, 1, 128), heads)],
+        out_shape=[jax.ShapeDtypeStruct((b, kv, rep, hd), q.dtype),
+                   jax.ShapeDtypeStruct((b, kv, 1, 128), jnp.int32)],
+        scratch_shapes=list(scratch),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=semantics),
+        interpret=platform.interpret(),
+    )(*ops)
+
+
+@functools.partial(jax.jit, static_argnames=("scheme", "per_slot"))
 def fused_page_attention(q, ke, kch, ksc, ve, vch, vsc, pos, *,
-                         scheme: str = "in-place", interpret: bool = True,
-                         per_slot: bool = False):
+                         scheme: str = "in-place", per_slot: bool = False):
     """Fused decode-at-use attention over gathered encoded KV strips.
 
     q:        (B, H, 1, hd) float query (hd % 8 == 0).
@@ -162,44 +242,10 @@ def fused_page_attention(q, ke, kch, ksc, ve, vch, vsc, pos, *,
         raise ValueError(f"scheme {scheme!r}; one of {KV_SCHEMES}")
     b, h, _, hd = q.shape
     s, kv = ke.shape[1], ke.shape[2]
-    rep = h // kv
-    nb = hd // 8
-    if kch is None:
-        kch = jnp.zeros((b, s, kv, nb), jnp.uint8)
-        vch = jnp.zeros((b, s, kv, nb), jnp.uint8)
-    q4 = q[:, :, 0, :].reshape(b, kv, rep, hd)  # head g*rep+r -> (g, r)
-    pos2 = pos.reshape(b, 1).astype(jnp.int32)
-
-    kern = functools.partial(_kernel, scheme=scheme, s=s)
-    strip = lambda bi, g: (bi, 0, g, 0)
-    out, flags = pl.pallas_call(
-        kern,
-        grid=(b, kv),
-        in_specs=[
-            pl.BlockSpec((1, 1, rep, hd), lambda bi, g: (bi, g, 0, 0)),
-            pl.BlockSpec((1, s, 1, hd), strip),
-            pl.BlockSpec((1, s, 1, nb), strip),
-            pl.BlockSpec((1, s), lambda bi, g: (bi, 0)),
-            pl.BlockSpec((1, s, 1, hd), strip),
-            pl.BlockSpec((1, s, 1, nb), strip),
-            pl.BlockSpec((1, s), lambda bi, g: (bi, 0)),
-            pl.BlockSpec((1, 1), lambda bi, g: (bi, 0)),
-            pl.BlockSpec((7, 8), lambda bi, g: (0, 0)),
-            pl.BlockSpec((8, 8), lambda bi, g: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, rep, hd), lambda bi, g: (bi, g, 0, 0)),
-            pl.BlockSpec((1, 1, 2), lambda bi, g: (bi, g, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, kv, rep, hd), q.dtype),
-            jax.ShapeDtypeStruct((b, kv, 2), jnp.int32),
-        ],
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel")),
-        interpret=interpret,
-    )(q4, ke, kch, ksc, ve, vch, vsc, pos2,
-      jnp.asarray(ecc.ROWMASK64), jnp.asarray(ecc.COLS64_BYBYTE))
+    out, flags = _call(
+        functools.partial(_kernel, s=s), q, ke, kch, ksc, ve, vch, vsc, pos,
+        scheme=scheme, sblk=s, seq_block=lambda: 0, grid=(b, kv),
+        semantics=("parallel", "parallel"))
     return out.reshape(b, h, 1, hd), _reduce_flags(flags, per_slot)
 
 
@@ -208,12 +254,12 @@ def fused_page_attention(q, ke, kch, ksc, ve, vch, vsc, pos, *,
 # ---------------------------------------------------------------------------
 
 
-def _chunked_kernel(q_ref, ke_ref, kch_ref, ksc_ref, ve_ref, vch_ref,
-                    vsc_ref, pos_ref, rowmask_ref, cols_ref, o_ref,
-                    flags_ref, m_ref, l_ref, acc_ref, *, scheme, chunk,
-                    nchunks):
+def _chunked_kernel(*refs, scheme, chunk, nchunks, has_checks):
+    r = _unpack(refs, has_checks)
+    m_ref, l_ref, acc_ref = r["scratch"]
+    flags_ref = r["flags"]
     c = pl.program_id(2)
-    pos = pos_ref[0, 0]
+    pos = r["pos"][pl.program_id(0)]
     base = c * chunk
 
     @pl.when(c == 0)
@@ -228,48 +274,43 @@ def _chunked_kernel(q_ref, ke_ref, kch_ref, ksc_ref, ve_ref, vch_ref,
 
     @pl.when(base <= pos)  # chunks wholly past the valid prefix contribute 0
     def _update():
-        qb = q_ref[0, 0].astype(jnp.float32)           # (rep, hd)
+        qb = r["q"][0, 0].astype(jnp.float32)          # (rep, hd)
         hd = qb.shape[-1]
-        tok = base + jax.lax.broadcasted_iota(jnp.int32, (chunk, 1), 0)
-        valid_col = tok <= pos                         # (chunk, 1)
-        kq, kcor, kdue = _decode_strip(
-            ke_ref[0, :, 0, :], kch_ref[0, :, 0, :], valid_col,
-            rowmask_ref[...], cols_ref[...], scheme=scheme)
-        vq, vcor, vdue = _decode_strip(
-            ve_ref[0, :, 0, :], vch_ref[0, :, 0, :], valid_col,
-            rowmask_ref[...], cols_ref[...], scheme=scheme)
-        kf = kq.astype(jnp.float32) * ksc_ref[0][:, None]   # (chunk, hd)
-        vf = vq.astype(jnp.float32) * vsc_ref[0][:, None]
+        valid_col = base + jax.lax.broadcasted_iota(
+            jnp.int32, (chunk, 1), 0) <= pos
+        valid_row = base + jax.lax.broadcasted_iota(
+            jnp.int32, (1, chunk), 1) <= pos
+        kq, vq, cor, due = _strips(r, valid_col, scheme)
+        kf = kq.astype(jnp.float32) * r["ksc"][0]      # (chunk, hd)
+        vf = vq.astype(jnp.float32) * r["vsc"][0]
         sc = jax.lax.dot_general(
             qb, kf, dimension_numbers=(((1,), (1,)), ((), ())))
         sc = sc * (1.0 / np.sqrt(hd))                  # (rep, chunk) f32
-        sc = jnp.where(valid_col.reshape(1, chunk), sc, -1e30)
+        sc = jnp.where(valid_row, sc, -1e30)
         m_prev = m_ref[:, :1]                          # (rep, 1)
         l_prev = l_ref[:, :1]
         m_cur = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_cur)
         p = jnp.exp(sc - m_cur)                        # (rep, chunk)
-        p = jnp.where(valid_col.reshape(1, chunk), p, 0.0)
+        p = jnp.where(valid_row, p, 0.0)
         l_ref[...] = jnp.broadcast_to(
             alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True),
             l_ref.shape)
         m_ref[...] = jnp.broadcast_to(m_cur, m_ref.shape)
         acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
             p, vf, dimension_numbers=(((1,), (0,)), ((), ())))
-        flags_ref[0, 0] = flags_ref[0, 0] + jnp.stack([kcor + vcor,
-                                                       kdue + vdue])
+        flags_ref[0, 0] += _flag_row(cor, due)
 
     @pl.when(c == nchunks - 1)
     def _finish():
-        o_ref[0, 0] = (acc_ref[...] / l_ref[:, :1]).astype(o_ref.dtype)
+        r["o"][0, 0] = (acc_ref[...] / l_ref[:, :1]).astype(r["o"].dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("scheme", "chunk_tokens",
-                                             "interpret", "per_slot"))
+                                             "per_slot"))
 def chunked_page_attention(q, ke, kch, ksc, ve, vch, vsc, pos, *,
                            scheme: str = "in-place",
                            chunk_tokens: int = 256,
-                           interpret: bool = True,
                            per_slot: bool = False):
     """Page-chunked online-softmax decode-at-use attention.
 
@@ -281,9 +322,11 @@ def chunked_page_attention(q, ke, kch, ksc, ve, vch, vsc, pos, *,
     docstring) — gate behind ``attention_impl="chunked"`` and validate
     against :func:`oracle_page_attention`. Flag counts ARE exact.
 
-    ``chunk_tokens`` is clamped to S; strips whose S is not a multiple of
-    the chunk are zero-padded (padded tokens sit past every valid ``pos``
-    and are masked, and zero pages are codec-clean for every scheme).
+    ``chunk_tokens`` is clamped to S and, below S, to a multiple of 32
+    tokens (a uint8 strip block must span whole 32-row TPU tiles); strips
+    whose S is not a multiple of the chunk are zero-padded (padded tokens
+    sit past every valid ``pos`` and are masked, and zero pages are
+    codec-clean for every scheme).
     """
     if scheme not in KV_SCHEMES:
         raise ValueError(f"scheme {scheme!r}; one of {KV_SCHEMES}")
@@ -292,57 +335,23 @@ def chunked_page_attention(q, ke, kch, ksc, ve, vch, vsc, pos, *,
     b, h, _, hd = q.shape
     s, kv = ke.shape[1], ke.shape[2]
     rep = h // kv
-    nb = hd // 8
-    if kch is None:
-        kch = jnp.zeros((b, s, kv, nb), jnp.uint8)
-        vch = jnp.zeros((b, s, kv, nb), jnp.uint8)
-    chunk = min(chunk_tokens, s)
+    chunk = s if chunk_tokens >= s else min(s, max(32, chunk_tokens
+                                                   - chunk_tokens % 32))
     pad = (-s) % chunk
     if pad:
-        grow = lambda a: jnp.pad(a, ((0, 0), (0, pad)) +
-                                 ((0, 0),) * (a.ndim - 2))
+        grow = lambda a: None if a is None else jnp.pad(
+            a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
         ke, kch, ve, vch = grow(ke), grow(kch), grow(ve), grow(vch)
         ksc, vsc = grow(ksc), grow(vsc)
     nc = (s + pad) // chunk
-    q4 = q[:, :, 0, :].reshape(b, kv, rep, hd)
-    pos2 = pos.reshape(b, 1).astype(jnp.int32)
-
-    kern = functools.partial(_chunked_kernel, scheme=scheme, chunk=chunk,
-                             nchunks=nc)
-    cstrip = lambda bi, g, c: (bi, c, g, 0)
-    out, flags = pl.pallas_call(
-        kern,
-        grid=(b, kv, nc),
-        in_specs=[
-            pl.BlockSpec((1, 1, rep, hd), lambda bi, g, c: (bi, g, 0, 0)),
-            pl.BlockSpec((1, chunk, 1, hd), cstrip),
-            pl.BlockSpec((1, chunk, 1, nb), cstrip),
-            pl.BlockSpec((1, chunk), lambda bi, g, c: (bi, c)),
-            pl.BlockSpec((1, chunk, 1, hd), cstrip),
-            pl.BlockSpec((1, chunk, 1, nb), cstrip),
-            pl.BlockSpec((1, chunk), lambda bi, g, c: (bi, c)),
-            pl.BlockSpec((1, 1), lambda bi, g, c: (bi, 0)),
-            pl.BlockSpec((7, 8), lambda bi, g, c: (0, 0)),
-            pl.BlockSpec((8, 8), lambda bi, g, c: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, rep, hd), lambda bi, g, c: (bi, g, 0, 0)),
-            pl.BlockSpec((1, 1, 2), lambda bi, g, c: (bi, g, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, kv, rep, hd), q.dtype),
-            jax.ShapeDtypeStruct((b, kv, 2), jnp.int32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((rep, 128), jnp.float32),   # running max m
-            pltpu.VMEM((rep, 128), jnp.float32),   # running normalizer l
-            pltpu.VMEM((rep, hd), jnp.float32),    # running accumulator
-        ],
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(q4, ke, kch, ksc, ve, vch, vsc, pos2,
-      jnp.asarray(ecc.ROWMASK64), jnp.asarray(ecc.COLS64_BYBYTE))
+    out, flags = _call(
+        functools.partial(_chunked_kernel, chunk=chunk, nchunks=nc),
+        q, ke, kch, ksc, ve, vch, vsc, pos, scheme=scheme, sblk=chunk,
+        seq_block=lambda c: c, grid=(b, kv, nc),
+        scratch=[pltpu.VMEM((rep, 128), jnp.float32),   # running max m
+                 pltpu.VMEM((rep, 128), jnp.float32),   # running normalizer l
+                 pltpu.VMEM((rep, hd), jnp.float32)],   # running accumulator
+        semantics=("parallel", "parallel", "arbitrary"))
     return out.reshape(b, h, 1, hd), _reduce_flags(flags, per_slot)
 
 

@@ -15,6 +15,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 
 from repro.core import quant, wot
+from . import platform
 
 DEFAULT_BLK = 4096
 
@@ -50,9 +51,8 @@ def _qt_kernel(w_ref, scale_ref, q_ref, *, blk, nblk):
     q_ref[...] = q.astype(jnp.int8)
 
 
-@functools.partial(jax.jit, static_argnames=("blk", "interpret"))
-def quantize_throttle(w_blocks: jnp.ndarray, *, blk: int = DEFAULT_BLK,
-                      interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("blk",))
+def quantize_throttle(w_blocks: jnp.ndarray, *, blk: int = DEFAULT_BLK):
     """(nblk, 8) f32 -> (int8 q (nblk, 8) WOT-compliant, scale f32 ()).
 
     nblk need not divide into ``blk`` tiles: the grid is ``pl.cdiv`` and the
@@ -68,7 +68,7 @@ def quantize_throttle(w_blocks: jnp.ndarray, *, blk: int = DEFAULT_BLK,
         in_specs=[pl.BlockSpec((blk, 8), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((1,), lambda i: (0,)),
         out_shape=jax.ShapeDtypeStruct((1,), jnp.float32),
-        interpret=interpret,
+        interpret=platform.interpret(),
     )(w_blocks.astype(jnp.float32))
     scale = jnp.maximum(absmax, 1e-12) / quant.QMAX
     q = pl.pallas_call(
@@ -78,6 +78,6 @@ def quantize_throttle(w_blocks: jnp.ndarray, *, blk: int = DEFAULT_BLK,
                   pl.BlockSpec((1,), lambda i: (0,))],
         out_specs=pl.BlockSpec((blk, 8), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((nblk, 8), jnp.int8),
-        interpret=interpret,
+        interpret=platform.interpret(),
     )(w_blocks.astype(jnp.float32), scale)
     return q, scale[0]

@@ -14,6 +14,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core import wot
+from . import platform
 
 DEFAULT_BLK_N = 4096
 
@@ -25,9 +26,9 @@ def _kernel(q_ref, out_ref):
     out_ref[...] = jnp.where(pos == 7, q, clamped).astype(jnp.int8)
 
 
-@functools.partial(jax.jit, static_argnames=("blk_n", "interpret"))
-def throttle(q_blocks: jnp.ndarray, *, blk_n: int = DEFAULT_BLK_N,
-             interpret: bool = True) -> jnp.ndarray:
+@functools.partial(jax.jit, static_argnames=("blk_n",))
+def throttle(q_blocks: jnp.ndarray, *,
+             blk_n: int = DEFAULT_BLK_N) -> jnp.ndarray:
     """(nblk, 8) int8 -> WOT-throttled (nblk, 8) int8."""
     nblk = q_blocks.shape[0]
     blk_n = min(blk_n, nblk)
@@ -38,5 +39,5 @@ def throttle(q_blocks: jnp.ndarray, *, blk_n: int = DEFAULT_BLK_N,
         in_specs=[pl.BlockSpec((blk_n, 8), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((blk_n, 8), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((nblk, 8), jnp.int8),
-        interpret=interpret,
+        interpret=platform.interpret(),
     )(q_blocks)

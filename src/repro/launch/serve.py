@@ -1,11 +1,16 @@
 """Protected-serving driver: batched decode with ECC-encoded weights.
 
-Demonstrates the full serving path at local scale: build a
-``ProtectionPolicy`` (scheme + backend selectable), encode the weights,
-report coverage, inject memory faults at a chosen rate, and decode-serve
-batched requests — faults are corrected on the fly.
+Runs the full serving path: build a ``ProtectionPolicy`` (scheme + backend
+selectable), encode random weights from ``--seed`` at the architecture's
+published widths (``--smoke`` for the reduced same-family config, the CPU
+size), report coverage, inject memory faults at a chosen rate, and
+decode-serve batched requests — faults are corrected on the fly. The
+encoded tree is built by one compiled init->encode program, so the float
+weights never exist whole on the device.
 
-  PYTHONPATH=src python -m repro.launch.serve --arch deepseek-7b \
+  PYTHONPATH=src python -m repro.launch.serve --arch qwen1.5-4b \
+      --backend pallas --kv-policy in-place-fused          # one TPU chip
+  PYTHONPATH=src python -m repro.launch.serve --arch deepseek-7b --smoke \
       --fault-rate 1e-4 --tokens 32 [--scheme in-place] [--backend xla] \
       [--policy attn-inplace-mlp-secded] [--autotune BENCH_kernels.json] \
       [--abft] [--act-clamp]
@@ -31,6 +36,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import configs, protection
+from repro.launch.compile_cache import setup_compile_cache
 from repro.models import lm
 from repro.serving import kvcache, protected
 
@@ -148,7 +154,10 @@ def run_burst_mode(cfg, enc, plan, args, repair_kit=None):
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="deepseek-7b")
+    ap.add_argument("--arch", default="qwen1.5-4b")
+    ap.add_argument("--smoke", action="store_true",
+                    help="serve the reduced same-family config (CPU size) "
+                         "instead of the published widths")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--tokens", type=int, default=16)
     ap.add_argument("--fault-rate", type=float, default=0.0)
@@ -206,11 +215,13 @@ def main():
                          "flags channel")
     args = ap.parse_args()
 
-    cfg = configs.get_smoke(args.arch)
+    setup_compile_cache()
+    cfg = (configs.get_smoke if args.smoke else configs.get)(args.arch)
     label = f"policy={args.policy}" if args.policy else f"scheme={args.scheme}"
-    print(f"[serve] {cfg.name} smoke config, {label}, "
-          f"backend={args.backend}, fault_rate={args.fault_rate}")
-    params = lm.init_params(cfg, jax.random.PRNGKey(args.seed))
+    print(f"[serve] {cfg.name} {'smoke' if args.smoke else 'published'} "
+          f"config, {label}, backend={args.backend}, "
+          f"fault_rate={args.fault_rate}")
+    params = lm.param_specs(cfg, jnp.bfloat16)
     if args.policy:
         policy = protection.get_policy_preset(args.policy,
                                               backend=args.backend,
@@ -227,7 +238,7 @@ def main():
                         for k, v in sorted(s["by_scheme"].items()))
     print(f"[serve] plan: schemes {{{schemes}}}, backends {s['by_backend']}, "
           f"{s['n_flat_padded']} flat-padded leaves")
-    enc = plan.encode_tree(params)
+    enc = protected.init_encoded(cfg, plan, jax.random.PRNGKey(args.seed))
     if args.abft or args.act_clamp:
         clamps = None
         if args.act_clamp:

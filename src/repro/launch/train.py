@@ -1,12 +1,12 @@
 """End-to-end training driver.
 
-Local mode (default, CPU): trains a reduced config of any assigned arch on
-synthetic data with the full production stack — QAT + WOT throttling, SGD
-momentum, grad accumulation, async ECC-protected checkpointing, resume after
-failure. Production mode (--mesh 16x16 on real hardware) uses the same code
-path with the sharded mesh.
+Trains an assigned arch at its published widths (``--smoke``: the reduced
+same-family config, the CPU size) on synthetic data with the full
+production stack — QAT + WOT throttling, SGD momentum, grad accumulation,
+async ECC-protected checkpointing, resume after failure.
 
-  PYTHONPATH=src python -m repro.launch.train --arch qwen1.5-4b --steps 50
+  PYTHONPATH=src python -m repro.launch.train --arch qwen1.5-4b --smoke \
+      --steps 50
 """
 from __future__ import annotations
 
@@ -30,7 +30,9 @@ def main():
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=64)
     ap.add_argument("--lr", type=float, default=3e-3)
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action="store_true",
+                    help="train the reduced same-family config (CPU size) "
+                         "instead of the published widths")
     ap.add_argument("--ckpt", default=None)
     ap.add_argument("--ckpt-every", type=int, default=10)
     ap.add_argument("--no-wot", action="store_true")

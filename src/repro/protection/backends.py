@@ -7,9 +7,9 @@ object, selected by a single ``backend=`` switch anywhere in the public API:
   Works everywhere, fuses into the surrounding XLA program; this is what the
   decode-on-read serving path compiles today.
 * ``"pallas"`` — the fused TPU kernels (``kernels/ops.py``): tiled VMEM
-  decode/encode and the decode+matmul ``ecc_qmatmul``. ``interpret=True`` by
-  default so the same switch validates on CPU; pass
-  ``get_backend("pallas", interpret=False)`` on real TPU.
+  decode/encode and the decode+matmul ``ecc_qmatmul``. Compiled on a TPU
+  backend and interpreted on CPU (``kernels.platform``), so the same switch
+  validates on CPU and runs natively on the chip.
 
 Backends only differ for the in-place (64,57,1) code — parity/secded72 have
 no Pallas kernels and always take the jnp path inside their schemes.
@@ -66,50 +66,28 @@ class XlaBackend(Backend):
 
 
 class PallasBackend(Backend):
-    """Tiled VMEM kernels. Arbitrary block shapes are handled by flattening
-    to (nblk, 8) and zero-padding nblk up to a tile multiple (a zero block
-    has syndrome 0, so padding decodes/encodes to itself)."""
+    """Tiled VMEM kernels over the byte plane a block view comes from
+    (``kernels.ecc_decode.plane``), any block shape; ``blk_n`` is the
+    blocks per grid step."""
 
     name = "pallas"
 
-    def __init__(self, *, interpret: bool = True, blk_n: int = 4096):
-        self.interpret = interpret
+    def __init__(self, *, blk_n: int = 32768):
         self.blk_n = blk_n
-
-    def _tile_pad(self, blocks2d: jnp.ndarray) -> tuple[jnp.ndarray, int]:
-        nblk = blocks2d.shape[0]
-        if nblk <= self.blk_n:
-            return blocks2d, nblk
-        pad = (-nblk) % self.blk_n
-        if pad:
-            blocks2d = jnp.concatenate(
-                [blocks2d, jnp.zeros((pad, 8), blocks2d.dtype)])
-        return blocks2d, nblk
 
     def encode64(self, blocks):
         from repro.kernels import ecc_encode
-        shape = blocks.shape
-        b2, nblk = self._tile_pad(blocks.astype(jnp.uint8).reshape(-1, 8))
-        out = ecc_encode.ecc_encode(b2, blk_n=min(self.blk_n, b2.shape[0]),
-                                    interpret=self.interpret)
-        return out[:nblk].reshape(shape)
+        return ecc_encode.ecc_encode(blocks, blk_n=self.blk_n)
 
     def decode64(self, blocks):
         from repro.kernels import ecc_decode
-        shape = blocks.shape
-        b2, nblk = self._tile_pad(blocks.astype(jnp.uint8).reshape(-1, 8))
-        dec, flags = ecc_decode.ecc_decode(
-            b2, blk_n=min(self.blk_n, b2.shape[0]), interpret=self.interpret)
-        dec = dec[:nblk].reshape(shape)
-        flags = flags[:nblk].reshape(shape[:-1])
-        single = (flags & 1) == 1
-        double = (flags & 2) == 2
-        return dec, single, double
+        dec, flags = ecc_decode.ecc_decode(blocks.astype(jnp.uint8),
+                                           blk_n=self.blk_n)
+        return dec, (flags & 1) == 1, (flags & 2) == 2
 
     def qmatmul(self, a_q, w_enc, a_scale, w_scale):
         from repro.kernels import ops
-        return ops.qmatmul_protected(a_q, w_enc, a_scale, w_scale,
-                                     interpret=self.interpret)
+        return ops.qmatmul_protected(a_q, w_enc, a_scale, w_scale)
 
 
 BACKENDS = {"xla": XlaBackend, "pallas": PallasBackend}

@@ -523,7 +523,9 @@ def compute_campaign(tree, policy=None, rates=(1e-3,), trials=2, key=None,
                                        jax.random.fold_in(rk, idx))
         rows.append((p, int(d), int(i)))
 
-    grid = tuple(tuple(float(out[r, t, 0]) / max(float(out[r, t, 1]), 1.0)
+    # a cell that drew no injection let nothing escape: coverage 1, not 0
+    grid = tuple(tuple(float(out[r, t, 0]) / float(out[r, t, 1])
+                       if out[r, t, 1] else 1.0
                        for t in range(trials)) for r in range(n_rates))
     dev = jax.devices()[0]
     return CampaignResult(

@@ -188,13 +188,11 @@ class ProtectedWeight:
         exact int32 accumulator scaled by ``a_scale * w_scale`` in f32."""
         if self.fuse:
             from repro.kernels.ecc_qmatmul import ecc_qmatmul
-            interpret = getattr(self.backend, "interpret", True)
             bm, bn, _bk = (self.int8_tiles or self.tiles or (128, 128, 0))
             res = ecc_qmatmul(q_x, self.pt.enc, self.pt.scale,
                               a_scale=a_scale, out_dtype=out_dtype,
-                              bm=bm, bn=bn, interpret=interpret,
-                              with_flags=True, with_abft=self.abft,
-                              clamp=self.clamp)
+                              bm=bm, bn=bn, with_flags=True,
+                              with_abft=self.abft, clamp=self.clamp)
             if self._track:
                 out, flags, (rows, col_mm) = res
                 self.record_abft(rows[:, 0], rows[:, 1], col_mm)
@@ -282,15 +280,13 @@ class ProtectedWeight:
             self.record_abft(row_mm, hits, col_mm)
             return acc.astype(x.dtype).reshape(*lead, n_out)
         from repro.kernels.ecc_qmatmul import ecc_qmatmul
-        interpret = getattr(self.backend, "interpret", True)
         # serving keeps full-K tiles (bk=0): one f32 dot per output tile, so
         # the accumulation order — and hence every logit — is bit-identical
         # to decode-then-matmul. The autotune bk only tunes the int8 path.
         bm, bn, _bk = self.tiles or (128, 128, 0)
         res = ecc_qmatmul(a2, self.pt.enc, self.pt.scale,
-                          bm=bm, bn=bn, bk=0, interpret=interpret,
-                          with_flags=True, with_abft=self.abft,
-                          clamp=self.clamp)
+                          bm=bm, bn=bn, bk=0, with_flags=True,
+                          with_abft=self.abft, clamp=self.clamp)
         if self._track:
             out, flags, (rows, col_mm) = res
             self.record_abft(rows[:, 0], rows[:, 1], col_mm)

@@ -207,7 +207,7 @@ def transcode_leaf(pt: ProtectedTensor, to_scheme, *, backend="xla"):
     be = get_backend(backend)
     q, corrected, due = frm.decode_with_flags(pt.enc, pt.checks, be)
     if to.requires_wot:
-        q = wot.throttle_q(q.reshape(-1)).reshape(q.shape)
+        q = wot.throttle_q(q)
     enc, checks = to.encode(q, be)
     new = ProtectedTensor(enc=enc, checks=checks, scale=pt.scale,
                           scheme_id=to.scheme_id,

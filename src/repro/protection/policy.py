@@ -243,7 +243,7 @@ class ProtectionPolicy:
         q = jnp.clip(jnp.round(w / scale), -quant.QMAX,
                      quant.QMAX).astype(jnp.int8)
         if self.throttle:
-            q = wot.throttle_q(q.reshape(-1)).reshape(w.shape)
+            q = wot.throttle_q(q)
         if w.ndim >= 1 and w.shape[-1] % BLOCK == 0:
             q_img = q                         # same-shape layout
         else:

@@ -78,7 +78,6 @@ class KVProtectionPolicy:
                (``kernels.paged_attention``) instead of the XLA
                decode-then-attend reference. Bit-identical by construction.
     page_size: tokens per page.
-    interpret: Pallas interpret mode for the fused kernel (CPU-safe).
     per_slot_flags: report KV (corrected, DUE) flags per BATCH SLOT
                instead of batch-summed scalars — ``flags["layers_kv"]``
                becomes (n_layers, 2, B) so the request front-end can
@@ -106,7 +105,6 @@ class KVProtectionPolicy:
     backend: str = "xla"
     fused: bool = False
     page_size: int = 16
-    interpret: bool = True
     per_slot_flags: bool = False
     attention_impl: str = "strip"
     chunk_pages: int = 16
@@ -292,7 +290,7 @@ def _encode_kv(kf: jnp.ndarray, policy: KVProtectionPolicy):
                  quant.QMAX).astype(jnp.int8)
     scheme = policy.scheme_obj
     if scheme.requires_wot:
-        q = wot.throttle_q(q.reshape(-1)).reshape(q.shape)
+        q = wot.throttle_q(q)
     enc, checks = scheme.encode(q, policy.backend)
     return enc, checks, scale[..., 0, 0]
 
@@ -594,14 +592,13 @@ def paged_gqa_decode(p, x, cfg: ArchConfig, lc, *, pos, wt=L.Identity,
             qh, ke, kch, ksc, ve, vch, vsc, pos,
             scheme=policy.scheme,
             chunk_tokens=policy.chunk_pages * policy.page_size,
-            interpret=policy.interpret, per_slot=policy.per_slot_flags)
+            per_slot=policy.per_slot_flags)
         L.record_kv_flags(flags[0], flags[1])
     elif policy.fused:
         from repro.kernels import paged_attention
         o, flags = paged_attention.fused_page_attention(
             qh, ke, kch, ksc, ve, vch, vsc, pos,
-            scheme=policy.scheme, interpret=policy.interpret,
-            per_slot=policy.per_slot_flags)
+            scheme=policy.scheme, per_slot=policy.per_slot_flags)
         L.record_kv_flags(flags[0], flags[1])
     else:
         o, corrected, due = _reference_paged_attention(

@@ -76,6 +76,17 @@ def make_plan(params, policy: Optional[protection.ProtectionPolicy] = None,
                                 mesh=mesh, param_spec_fn=param_spec_fn)
 
 
+def init_encoded(cfg: ArchConfig, plan: protection.ProtectionPlan, key,
+                 dtype=jnp.bfloat16):
+    """Random weights from ``key``, encoded under ``plan``, built as ONE
+    compiled program: the float tree (a ``dtype`` source, bf16 by default)
+    exists only inside it, so a model whose float32 tree would not fit the
+    device still builds. Build ``plan`` from ``lm.param_specs(cfg,
+    dtype)``."""
+    return jax.jit(lambda k: plan.encode_tree(
+        lm.init_params(cfg, k, dtype)))(key)
+
+
 # ---------------------------------------------------------------------------
 # decode-at-use routing
 # ---------------------------------------------------------------------------

@@ -15,6 +15,7 @@ def _run(body: str, n_dev=8):
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={n_dev}"
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import auto_mesh
         {textwrap.indent(textwrap.dedent(body), '        ').strip()}
         print("SUBPROC_OK")
     """)
@@ -54,7 +55,7 @@ def test_sharded_train_step_runs_on_2x4_mesh():
         from repro.models.config import ShapeConfig
 
         cfg = configs.get_smoke("minitron-4b").with_(microbatch=2)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = auto_mesh((2, 4), ("data", "model"))
         shape = ShapeConfig("t", 32, 8, "train")
         step, args, in_sh, out_sh = S.train_cell(cfg, shape, mesh, chunk=16)
         as_named = lambda t: jax.tree.map(
@@ -78,7 +79,7 @@ def test_pipeline_matches_sequential():
         from jax.sharding import Mesh
         from repro.distributed.pipeline import make_pipeline_fn
         n_stages, n_micro, d = 4, 8, 16
-        mesh = jax.make_mesh((n_stages,), ("stage",))
+        mesh = auto_mesh((n_stages,), ("stage",))
         def stage_fn(w, x):
             return jnp.tanh(x @ w)
         ws = jax.random.normal(jax.random.PRNGKey(0), (n_stages, d, d)) * 0.5
@@ -98,16 +99,15 @@ def test_pipeline_matches_sequential():
 def test_compressed_psum_shard_map():
     _run("""
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
         from repro.training.compress import compressed_psum
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = auto_mesh((8,), ("data",))
         g = jax.random.normal(jax.random.PRNGKey(0), (8, 128))
         res = jnp.zeros((8, 128))
         def f(g, r):
             out, nr = compressed_psum(g[0], r[0], "data")
             return out[None], nr[None]
         with mesh:
-            out, nr = shard_map(f, mesh=mesh, in_specs=(P("data"), P("data")),
+            out, nr = jax.shard_map(f, mesh=mesh, in_specs=(P("data"), P("data")),
                                 out_specs=(P("data"), P("data")))(g, res)
         import numpy as np
         mean_ref = np.mean(np.asarray(g), axis=0)
@@ -128,7 +128,7 @@ def test_plan_spec_tree_flat_padded_sharded_on_2d_mesh():
     _run("""
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro import protection
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = auto_mesh((2, 4), ("data", "model"))
         rng = np.random.default_rng(0)
         def wotp(shape):
             q = rng.integers(-64, 64, size=int(np.prod(shape))).astype(np.int8)
@@ -179,7 +179,7 @@ def test_decode_cell_espec_and_logits_spec_on_small_mesh():
         from repro.protection import is_protected_tensor
 
         cfg = configs.get_smoke("qwen1.5-4b")
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = auto_mesh((2, 4), ("data", "model"))
         shape = ShapeConfig("d", 64, 8, "decode")   # b=8: 8 % 2 == 0
         policy = protection.get_policy_preset("attn-inplace-mlp-secded")
         step, args, in_sh, out_sh = S.decode_cell(cfg, shape, mesh,
@@ -204,6 +204,6 @@ def test_multipod_mesh_axes():
         sys.argv = ["x"]
         from repro.launch.mesh import make_production_mesh
         # 16 devices can't build the real 512 mesh; check axis logic only
-        m = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        m = auto_mesh((2, 2, 2), ("pod", "data", "model"))
         assert m.axis_names == ("pod", "data", "model")
     """)

@@ -102,3 +102,36 @@ def test_ops_wrappers():
     t = ops.throttle_flat(q)
     from repro.core import wot
     assert wot.satisfies_constraint(t)
+
+
+def _entry_points():
+    from repro.kernels import (ecc_encode, flash_attention, paged_attention,
+                               quant_throttle)
+    from repro.protection.backends import PallasBackend
+    from repro.serving.kvcache import KVProtectionPolicy
+    return {
+        "ecc_decode": ecc_decode, "ecc_encode": ecc_encode.ecc_encode,
+        "ecc_qmatmul": ecc_qmatmul, "throttle": throttle,
+        "quantize_throttle": quant_throttle.quantize_throttle,
+        "flash_attention": flash_attention.flash_attention,
+        "fused_page_attention": paged_attention.fused_page_attention,
+        "chunked_page_attention": paged_attention.chunked_page_attention,
+        "ops.decode_weights": ops.decode_weights,
+        "ops.qmatmul_protected": ops.qmatmul_protected,
+        "ops.attention": ops.attention,
+        "PallasBackend": PallasBackend, "KVProtectionPolicy":
+            KVProtectionPolicy}
+
+
+@pytest.mark.parametrize("name", sorted(_entry_points()))
+def test_no_interpret_option(name):
+    """The Pallas mode comes from the platform (kernels.platform), never
+    from a caller: no entry point, backend or KV policy takes it."""
+    import inspect
+    fn = _entry_points()[name]
+    assert "interpret" not in inspect.signature(fn).parameters
+
+
+def test_interpret_follows_the_platform():
+    from repro.kernels import platform
+    assert platform.interpret() == (jax.default_backend() != "tpu")

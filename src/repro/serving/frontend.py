@@ -281,7 +281,7 @@ class ServingFrontend:
             del self._published[pid]
             released = self.allocator.free((pid,))
             if released:
-                self.cache = kvcache.zero_pages(self.cache, released)
+                self._page_op(kvcache.zero_pages, released)
 
     def drop_prefix_cache(self) -> int:
         """Release every cached prefix page (the index's own references);
@@ -341,13 +341,12 @@ class ServingFrontend:
             if cow:
                 src, dst = shared[-1], fresh[0]
                 self.allocator.retain(shared[:-1])
-                self.cache = kvcache.copy_page(self.cache, src, dst)
+                self._page_op(kvcache.copy_page, src, dst)
                 pages = shared[:-1] + (dst,) + fresh[1:]
             else:
                 self.allocator.retain(shared)
                 pages = shared + fresh
-            self.cache = kvcache.set_slot_pages(self.cache, free_slot,
-                                                pages)
+            self._page_op(kvcache.set_slot_pages, free_slot, pages)
             enq_step, enq_s = self._pending_meta.pop(req.rid)
             slot = _Slot(req, pages, self.step_no, enq_step, enq_s)
             # shared pages' K/V is already in the pool: skip straight
@@ -417,28 +416,35 @@ class ServingFrontend:
         the serve compute so written-back corrections land before anything
         decodes them."""
         mig = self._migrator
-        if (mig is not None and not mig.done
-                and self.step_no % self._migrate_every == 0):
-            self.enc_params, recs = mig.step(self.enc_params)
-            self.plan = mig.plan
-            for r in recs:
-                self.telemetry.emit("migrate", step=self.step_no,
-                                    phase="promote",
-                                    pending=len(mig.pending), **r)
-        if self.scrub_every and self.step_no % self.scrub_every == 0:
-            self.enc_params, wst = self.scrubber.scrub_weights(
-                self.enc_params)
-            if wst["due_paths"] and self.repair_kit is not None:
-                self._repair(wst["due_paths"])
-            self.cache, kst = self.scrubber.scrub_kv(
-                self.cache, self.policy,
-                occupied=self.allocator.live_pages(),
-                busy=self._busy_pages())
-            self.telemetry.emit(
-                "scrub", step=self.step_no,
-                w_scanned=wst["scanned"], w_corrected=wst["corrected"],
-                w_due=wst["due"], kv_scanned=kst["scanned"],
-                kv_corrected=kst["corrected"], kv_due=kst["due"])
+        migrate = (mig is not None and not mig.done
+                   and self.step_no % self._migrate_every == 0)
+        scrub = bool(self.scrub_every) and \
+            self.step_no % self.scrub_every == 0
+        if not (migrate or scrub):
+            return
+        with self.telemetry.span("heal"):
+            if migrate:
+                self.enc_params, recs = mig.step(self.enc_params)
+                self.plan = mig.plan
+                for r in recs:
+                    self.telemetry.emit("migrate", step=self.step_no,
+                                        phase="promote",
+                                        pending=len(mig.pending), **r)
+            if scrub:
+                self._scrub()
+
+    def _scrub(self):
+        self.enc_params, wst = self.scrubber.scrub_weights(self.enc_params)
+        if wst["due_paths"] and self.repair_kit is not None:
+            self._repair(wst["due_paths"])
+        self.cache, kst = self.scrubber.scrub_kv(
+            self.cache, self.policy, occupied=self.allocator.live_pages(),
+            busy=self._busy_pages())
+        self.telemetry.emit(
+            "scrub", step=self.step_no,
+            w_scanned=wst["scanned"], w_corrected=wst["corrected"],
+            w_due=wst["due"], kv_scanned=kst["scanned"],
+            kv_corrected=kst["corrected"], kv_due=kst["due"])
 
     def final_scrub(self) -> dict:
         """One full at-rest pass, meant for after the loop drains: every
@@ -482,10 +488,10 @@ class ServingFrontend:
         # LAST reference died re-enter the pool — zero exactly those
         # before anything can re-allocate them (pages still mapped by
         # other slots or the prefix cache must keep their bytes)
-        self.cache = kvcache.set_slot_pages(self.cache, idx, ())
+        self._page_op(kvcache.set_slot_pages, idx, ())
         released = self.allocator.free(s.pages)
         if released:
-            self.cache = kvcache.zero_pages(self.cache, released)
+            self._page_op(kvcache.zero_pages, released)
         self._slots[idx] = None
         ev = {"rid": s.req.rid, "step": self.step_no, "slot": idx,
               "n_generated": n_gen, "kv_corrected": int(s.kv_corrected),
@@ -499,39 +505,84 @@ class ServingFrontend:
             ev["tpot_ms"] = ((now - s.first_s) / max(1, n_gen - 1)) * 1e3
         self.telemetry.emit("finish", **ev)
 
+    def _page_op(self, fn, *args):
+        """Apply one eager ``kvcache`` page program to the cache: a
+        ``serve.pages`` span, counted in the step's ``page_ops``."""
+        with self.telemetry.span("pages"):
+            self.cache = fn(self.cache, *args)
+        self.telemetry.count("page_ops")
+
     def step(self):
         """One loop iteration: admit, run the compiled step over all
         slots (idle slots feed a keep-alive token into their parking
-        page), sample greedily, advance lifecycles, emit telemetry."""
-        self._admit()
-        self._heal()
-        t0 = time.perf_counter()
-        tokens = np.zeros((self.slots_n, 1), np.int32)
-        pos = np.zeros((self.slots_n,), np.int32)
-        for i, s in enumerate(self._slots):
-            if s is None:
-                continue
-            if s.consumed < len(s.req.prompt):
-                tokens[i, 0] = s.req.prompt[s.consumed]
-            else:
-                tokens[i, 0] = s.generated[-1]
-            pos[i] = s.consumed
-        logits, self.cache, flags = self.serve_step(
-            self.enc_params, self.cache, jnp.asarray(tokens),
-            jnp.asarray(pos))
-        sampled = np.asarray(jnp.argmax(logits[:, -1, :], axis=-1))
-        kv = np.asarray(flags["layers_kv"]).sum(axis=0)   # (2,) | (2, B)
-        w = np.asarray(flags["top"]) + np.asarray(flags["layers"]).sum(0)
-        # ABFT channel (only present when the plan guards some leaves):
-        # layer rows (L, 2) or per-slot (L, 2, B), plus the top row — the
-        # decode step's output rows ARE the batch slots, so per-slot rows
-        # attribute compute faults to requests exactly
-        ab = flags.get("layers_abft")
-        if ab is not None:
-            ab = np.asarray(ab).sum(axis=0) + np.asarray(flags["top_abft"])
-        t1 = time.perf_counter()
+        page), sample greedily, advance lifecycles, emit telemetry. Each
+        phase is a ``serve.*`` span inside ``serve.step`` (module
+        :mod:`~repro.serving.telemetry`)."""
+        tel = self.telemetry
+        with tel.step(self.step_no) as timing:
+            with tel.span("admit"):
+                self._admit()
+            self._heal()
+            with tel.span("inputs"):
+                tokens = np.zeros((self.slots_n, 1), np.int32)
+                pos = np.zeros((self.slots_n,), np.int32)
+                for i, s in enumerate(self._slots):
+                    if s is None:
+                        continue
+                    if s.consumed < len(s.req.prompt):
+                        tokens[i, 0] = s.req.prompt[s.consumed]
+                    else:
+                        tokens[i, 0] = s.generated[-1]
+                    pos[i] = s.consumed
+                tokens, pos = jnp.asarray(tokens), jnp.asarray(pos)
+            # both calls only dispatch; the wait is on the sampled tokens
+            with tel.span("dispatch"):
+                logits, self.cache, flags = self.serve_step(
+                    self.enc_params, self.cache, tokens, pos)
+                top = jnp.argmax(logits[:, -1, :], axis=-1)
+            with tel.span("wait"):
+                sampled = np.asarray(top)
+            with tel.span("fetch"):
+                kv = np.asarray(flags["layers_kv"]).sum(axis=0)  # (2,)|(2, B)
+                w = np.asarray(flags["top"]) + \
+                    np.asarray(flags["layers"]).sum(0)
+                # ABFT channel (only present when the plan guards some
+                # leaves): layer rows (L, 2) or per-slot (L, 2, B), plus
+                # the top row — the decode step's output rows ARE the
+                # batch slots, so per-slot rows attribute compute faults
+                # to requests exactly
+                ab = flags.get("layers_abft")
+                if ab is not None:
+                    ab = np.asarray(ab).sum(axis=0) + \
+                        np.asarray(flags["top_abft"])
+            t1 = time.perf_counter()
+            per_slot = kv.ndim == 2
+            with tel.span("advance"):
+                self._advance(sampled, kv, ab, per_slot, t1)
+            with tel.span("finish"):
+                for i, s in enumerate(self._slots):
+                    if s is not None and len(s.generated) >= s.req.max_new:
+                        self._finish(i)
+            # emitted after finishes so pool_free reflects this step's
+            # frees — summarize() reads the last step's pool_free as the
+            # leak check
+            ev = dict(
+                step=self.step_no, active=self.active,
+                queue_depth=len(self.queue),
+                pool_free=self.allocator.free_count,
+                pool_cached=len(self._prefix_index),
+                kv_corrected=int(kv.sum(axis=-1)[0] if per_slot else kv[0]),
+                kv_due=int(kv.sum(axis=-1)[1] if per_slot else kv[1]),
+                w_corrected=int(w[0]), w_due=int(w[1]))
+            if ab is not None:
+                ev["abft_mismatches"] = int(ab[0].sum())
+                ev["clamp_hits"] = int(ab[1].sum())
+        tel.emit("step", **ev, **timing)
+        self.step_no += 1
 
-        per_slot = kv.ndim == 2
+    def _advance(self, sampled, kv, ab, per_slot: bool, t1: float):
+        """Per-slot bookkeeping after a step: fault counts, the consumed
+        prompt, the sampled token, and ``first_token`` events."""
         for i, s in enumerate(self._slots):
             if s is None:
                 continue
@@ -559,24 +610,6 @@ class ServingFrontend:
                         "first_token", rid=s.req.rid, step=self.step_no,
                         slot=i, ttft_steps=self.step_no - s.enqueue_step,
                         ttft_s=t1 - s.enqueue_s)
-        for i, s in enumerate(self._slots):
-            if s is not None and len(s.generated) >= s.req.max_new:
-                self._finish(i)
-        # emitted after finishes so pool_free reflects this step's frees —
-        # summarize() reads the last step's pool_free as the leak check
-        ev = dict(
-            step=self.step_no, active=self.active,
-            queue_depth=len(self.queue),
-            pool_free=self.allocator.free_count,
-            pool_cached=len(self._prefix_index),
-            kv_corrected=int(kv.sum(axis=-1)[0] if per_slot else kv[0]),
-            kv_due=int(kv.sum(axis=-1)[1] if per_slot else kv[1]),
-            w_corrected=int(w[0]), w_due=int(w[1]))
-        if ab is not None:
-            ev["abft_mismatches"] = int(ab[0].sum())
-            ev["clamp_hits"] = int(ab[1].sum())
-        self.telemetry.emit("step", **ev, step_ms=(t1 - t0) * 1e3)
-        self.step_no += 1
 
     def run(self, max_steps: int = 10_000):
         """Step until queue and slots drain (or ``max_steps``)."""

@@ -35,11 +35,14 @@ finish     rid, step, slot, n_generated, kv_corrected, kv_due, pool_free,
            clamps) and the request saw hits, also abft_mismatches,
            clamp_hits
 step       step, active, queue_depth, pool_free, pool_cached,
-           kv_corrected, kv_due, w_corrected, w_due, [step_ms]; with an
+           kv_corrected, kv_due, w_corrected, w_due, page_ops, [step_ms,
+           admit_ms, heal_ms, inputs_ms, dispatch_ms, wait_ms, fetch_ms,
+           advance_ms, finish_ms, pages_ms, compile_ms, gc_ms]; with an
            ABFT/clamp-guarded plan also abft_mismatches, clamp_hits
            (integer counts from the compute-fault channel — no wall
            suffix, so they sit INSIDE the deterministic view and seeded
-           replays must reproduce them bit for bit)
+           replays must reproduce them bit for bit); in a step that
+           compiled also [compile_sites_ms]
 scrub      step, w_scanned, w_corrected, w_due, kv_scanned, kv_corrected,
            kv_due  (one budgeted healing pass; w_due counts leaves left
            un-written-back for repair)
@@ -56,19 +59,41 @@ All healing events are pure functions of the logical step and the seeded
 fault stream — no wall fields — so they sit inside the deterministic
 view. ``pool_cached`` counts prefix-cache-held pages; the leak check is
 ``initial_free - final_free - final_cached == 0`` (cached pages are
-referenced on purpose, not leaked)."""
+referenced on purpose, not leaked).
+
+Step spans and counters
+-----------------------
+:meth:`TelemetryCollector.step` opens a ``serve.step`` profiler span
+(``jax.profiler.TraceAnnotation``, with the step number as its ``step``
+argument) and :meth:`TelemetryCollector.span` a ``serve.<name>`` span
+inside it, so a profile of a serving process holds the front-end's phases
+on the device trace's clock. Each span's duration on ``time.perf_counter``
+also adds to the step's ``<name>_ms`` field; ``step_ms`` is the whole
+``serve.step`` span. ``compile_ms`` is the union of the JAX compile
+stages (trace, lowering, backend compile or persistent-cache load) that
+ran while the step was open, ``compile_sites_ms`` names them by innermost
+span and program, and ``gc_ms`` sums the Python GC pauses inside the
+step. One process-wide JAX monitoring listener and one ``gc.callbacks``
+entry, registered on the first step, route to the step open at the time."""
 
 from __future__ import annotations
 
+import contextlib
 import csv
+import gc
 import json
 import math
+import re
+import threading
+import time
 from typing import IO, Optional
+
+from jax.profiler import TraceAnnotation
 
 __all__ = [
     "TelemetryCollector", "deterministic_view", "percentile",
     "summarize", "write_summary", "load_summary", "write_requests_csv",
-    "SUMMARY_SCHEMA", "SUPPORTED_SCHEMAS",
+    "SUMMARY_SCHEMA", "SUPPORTED_SCHEMAS", "STEP_SPANS",
 ]
 
 # v2 adds the ``healing`` roll-up (scrub / migrate / repair totals and the
@@ -81,15 +106,152 @@ SUPPORTED_SCHEMAS = ("burst_sim/v1", "burst_sim/v2")
 
 _WALL_SUFFIXES = ("_s", "_ms")
 
+# the spans of a serve step, each a ``<name>_ms`` field of its ``step``
+# event: the phases in the order they run, then ``pages``, which nests
+# inside ``admit`` and ``finish``
+STEP_SPANS = ("admit", "heal", "inputs", "dispatch", "wait", "fetch",
+              "advance", "finish", "pages")
+
+
+class _Step:
+    """What one open serve step has accumulated."""
+
+    __slots__ = ("t0", "ms", "counts", "stack", "compiles", "gc_ms", "gc_t0")
+
+    def __init__(self):
+        self.t0 = time.perf_counter()
+        self.ms = dict.fromkeys(STEP_SPANS, 0.0)
+        self.counts = {"page_ops": 0}
+        self.stack: list = []          # names of the open spans
+        self.compiles: list = []       # (start, end, span:program)
+        self.gc_ms = 0.0
+        self.gc_t0: Optional[float] = None
+
+    def fields(self, t1: float) -> dict:
+        out = {"step_ms": (t1 - self.t0) * 1e3}
+        out.update((f"{k}_ms", v) for k, v in self.ms.items())
+        sites: dict = {}
+        total, end = 0.0, -math.inf
+        # the union of the stages: a stage nested in another (a jitted
+        # callee traced inside its caller) counts once, under its caller
+        for s, e, site in sorted(self.compiles,
+                                 key=lambda c: (c[0], -c[1])):
+            if e <= end:
+                continue
+            d = e - max(s, end)
+            total += d
+            sites[site] = sites.get(site, 0.0) + d * 1e3
+            end = e
+        out["compile_ms"] = total * 1e3
+        out["gc_ms"] = self.gc_ms
+        out.update(self.counts)
+        if sites:
+            out["compile_sites_ms"] = sites
+        return out
+
+
+# the step open in this process, if any; the listeners below route to it
+_OPEN: Optional[_Step] = None
+_LISTENING = False
+_INSTALL = threading.Lock()
+_COMPILE_EVENTS: frozenset = frozenset()
+
+
+def _on_compile(event: str, start: float, end: float, **kw):
+    rec = _OPEN
+    if rec is None or event not in _COMPILE_EVENTS:
+        return
+    # the lowering and backend stages name the program jit(<name>)
+    prog = re.sub(r"^jit\((.*)\)$", r"\1", str(kw.get("fun_name", "?")))
+    rec.compiles.append(
+        (start, end, f"{rec.stack[-1] if rec.stack else 'step'}:{prog}"))
+
+
+def _on_gc(phase: str, info: dict):
+    rec = _OPEN
+    if rec is None:
+        return
+    if phase == "start":
+        rec.gc_t0 = time.perf_counter()
+    elif rec.gc_t0 is not None:
+        rec.gc_ms += (time.perf_counter() - rec.gc_t0) * 1e3
+        rec.gc_t0 = None
+
+
+def _listen():
+    """Register the process's one compile listener and one GC callback,
+    the first time a step opens."""
+    global _LISTENING, _COMPILE_EVENTS
+    if _LISTENING:
+        return
+    with _INSTALL:
+        if _LISTENING:
+            return
+        import jax.monitoring
+        from jax._src import dispatch
+        # JAX's compile stages as its monitoring names them. The backend
+        # stage wraps the persistent-cache lookup, so a cache load counts
+        # there, and the cache's own retrieval-time event would count it
+        # twice
+        _COMPILE_EVENTS = frozenset((dispatch.JAXPR_TRACE_EVENT,
+                                     dispatch.JAXPR_TO_MLIR_MODULE_EVENT,
+                                     dispatch.BACKEND_COMPILE_EVENT))
+        jax.monitoring.register_event_time_span_listener(_on_compile)
+        gc.callbacks.append(_on_gc)
+        _LISTENING = True
+
 
 class TelemetryCollector:
     """Accumulates events in order; optionally streams them to a JSONL
     file as they arrive. Events are plain dicts with an ``event`` type
-    key — see the module docstring for the vocabulary."""
+    key — see the module docstring for the vocabulary. :meth:`step` and
+    :meth:`span` time a serve step and its phases (module docstring)."""
 
     def __init__(self, path: Optional[str] = None):
         self.events: list = []
         self._fh: Optional[IO] = open(path, "w") if path else None
+        self._step: Optional[_Step] = None
+
+    @contextlib.contextmanager
+    def step(self, n: int):
+        """Open serve step ``n``: a ``serve.step`` span. Yields a dict
+        that, on exit, receives the step's wall fields and counters for
+        its ``step`` event."""
+        global _OPEN
+        _listen()
+        rec = _Step()
+        out: dict = {}
+        self._step = _OPEN = rec
+        try:
+            with TraceAnnotation("serve.step", step=n):
+                yield out
+        finally:
+            out.update(rec.fields(time.perf_counter()))
+            self._step = None
+            if _OPEN is rec:
+                _OPEN = None
+
+    @contextlib.contextmanager
+    def span(self, name: str):
+        """A ``serve.<name>`` span (``name`` one of ``STEP_SPANS``); inside
+        an open step its duration adds to the step's ``<name>_ms``."""
+        rec = self._step
+        with TraceAnnotation(f"serve.{name}"):
+            if rec is None:
+                yield
+                return
+            rec.stack.append(name)
+            t0 = time.perf_counter()
+            try:
+                yield
+            finally:
+                rec.ms[name] += (time.perf_counter() - t0) * 1e3
+                rec.stack.pop()
+
+    def count(self, name: str):
+        """Add one to the open step's counter ``name``."""
+        if self._step is not None:
+            self._step.counts[name] += 1
 
     def emit(self, event: str, **fields) -> dict:
         rec = {"event": event, **fields}

@@ -19,6 +19,20 @@ standalone one:
 * the syndrome picks the flip mask per lane, and the sign-bit restore is a
   per-lane select.
 
+Packed words. Every step above works on each byte alone: a syndrome is an
+XOR of 7-bit code columns, so it never carries into the next byte, the
+group reduction runs along lanes, and the sign restore is bit arithmetic
+within a byte. So a ``(R, W)`` uint8 tile bitcast to ``(R/4, W)`` int32
+words (``pltpu.bitcast``: four rows of one lane per word, every block
+position still in its lane) decodes four bytes per 32-bit op.
+:func:`syndrome_words` gives each block's syndrome, packed the same way,
+on the last lane of its block (the reduction half of the group XOR: a
+check for zero needs no broadcast back), and :func:`restore_words` the
+packed sign restore. A tile whose syndromes are all zero decodes to
+exactly what :func:`decode_lanes` returns for it, with no flags, so only a
+tile with a nonzero syndrome needs the per-byte correction. The fused
+matmul takes this path (``ecc_qmatmul``).
+
 The standalone kernels take ``(..., nb, 8)`` blocks but run on the 2-D
 byte plane those blocks view (:func:`plane`), so every vreg lane carries
 data and no 8-wide minor dim is ever laid out on the device.
@@ -40,6 +54,13 @@ DEFAULT_BLK_N = 32768  # blocks per grid step (256 KiB tiles)
 LANES = 128
 MAX_TILE_LANES = 2048
 _SIGN_KEEP = 0xFF ^ (1 << ecc.CHECK_BIT)
+_WORD = 0x01010101  # bit 0 of each byte of a packed 32-bit word
+
+
+def _splat(byte: int, unit: int) -> int:
+    """``byte`` in every byte that ``unit`` marks (1: one byte per int32
+    lane, ``_WORD``: four), as an int32 constant."""
+    return int(np.array(byte * unit, np.uint32).view(np.int32))
 
 
 def code_table(width: int) -> np.ndarray:
@@ -52,24 +73,57 @@ def _byte_pos(shape):
     return jax.lax.broadcasted_iota(jnp.int32, shape, len(shape) - 1) & 7
 
 
+def _group_fold(t):
+    """XOR over each aligned 8-lane group onto its last lane, 8g+7; the
+    group's other lanes are left holding partial XORs."""
+    for s in (1, 2, 4):
+        t = t ^ pltpu.roll(t, s, t.ndim - 1)
+    return t
+
+
 def _group_xor(t):
     """XOR over each aligned 8-lane group, broadcast back to its lanes."""
     w = t.shape[-1]
-    ax = t.ndim - 1
-    for s in (1, 2, 4):          # lane 8g+7 ends up holding the group XOR
-        t = t ^ pltpu.roll(t, s, ax)
-    t = jnp.where(_byte_pos(t.shape) == 7, t, 0)
+    t = jnp.where(_byte_pos(t.shape) == 7, _group_fold(t), 0)
     for s in (1, 2, 4):          # copy it down to lanes 8g..8g+6
-        t = t ^ pltpu.roll(t, w - s, ax)
+        t = t ^ pltpu.roll(t, w - s, t.ndim - 1)
+    return t
+
+
+def _columns(x, table, unit=1):
+    """XOR of the code columns of the set bits of each byte of ``x``: int32
+    bytes (``unit`` 1) or packed words (``_WORD``, four bytes per word)."""
+    t = jnp.zeros_like(x)
+    for j in range(8):
+        t = t ^ (((x >> j) & unit) * table[j:j + 1, :])
     return t
 
 
 def _syndrome(x, table):
     """x (R, W) int32 bytes -> (R, W) syndrome of each lane's block."""
-    t = jnp.zeros_like(x)
-    for j in range(8):
-        t = t ^ (((x >> j) & 1) * table[j:j + 1, :])
-    return _group_xor(t)
+    return _group_xor(_columns(x, table))
+
+
+def _restore(x, unit=1):
+    """Sign-bit restore: bit 6 of bytes 0..6 of each block <- bit 7."""
+    restored = ((x & _splat(_SIGN_KEEP, unit)) |
+                ((x >> 1) & _splat(1 << ecc.CHECK_BIT, unit)))
+    return jnp.where(_byte_pos(x.shape) == 7, x, restored)
+
+
+def syndrome_words(w, table):
+    """w (R/4, W) int32: a ``(R, W)`` byte tile bitcast to words (four
+    bytes of one lane per word); table: :func:`code_table` ``(8, W)``.
+    Returns each block's syndrome on the last lane of its block (byte 7),
+    packed like ``w``: every byte stays below 128, so none carries into the
+    next. The block's other lanes hold partial XORs; mask them off."""
+    return _group_fold(_columns(w, table, _WORD))
+
+
+def restore_words(w):
+    """Packed sign restore of a word tile whose syndromes are all zero:
+    :func:`decode_lanes`' output bytes, packed like ``w``."""
+    return _restore(w, _WORD)
 
 
 def decode_lanes(x, table):
@@ -83,8 +137,7 @@ def decode_lanes(x, table):
     for j in range(8):
         flip = flip | jnp.where(syn == table[j:j + 1, :], 1 << j, 0)
     cor = jnp.where(single, x ^ flip, x)
-    restored = (cor & _SIGN_KEEP) | ((cor >> 1) & (1 << ecc.CHECK_BIT))
-    return jnp.where(_byte_pos(x.shape) == 7, cor, restored), single, double
+    return _restore(cor), single, double
 
 
 def encode_lanes(x, table):

@@ -23,6 +23,26 @@ tiles are masked (activation columns past K zeroed, flag counts restricted
 to real blocks) so production shapes need no divisibility beyond
 N % 8 == 0.
 
+The fill has a fast pass and a correcting one. Where the chunk's row count
+``rc`` is a multiple of 32 (every serving shape), each uint8 chunk is
+``pltpu.bitcast`` to int32 words, four rows of one lane per word, and the
+fast pass computes every block's syndrome four bytes per op
+(``ecc_decode.syndrome_words``), stores the packed sign restore
+(``restore_words``) bitcast back to int8, and ORs the syndromes of real
+blocks (rows < K, lanes < N: edge-tile padding never counts) into one
+accumulator for the whole slot. It takes several chunks per loop step (up
+to ``_FAST_ROWS`` rows), which gives the scheduler independent work to
+overlap. Only if the accumulator is nonzero does the correcting pass
+re-check each chunk and run the per-byte ``decode_lanes`` path, with its
+flag counts, on each chunk that holds a faulty block, overwriting what the
+fast pass stored there. A chunk whose syndromes are all zero decodes to
+exactly its sign-restored bytes with no flags, so the strip and the flags
+are bit-identical to a per-byte decode of every chunk. Lane ``_SLOW`` of
+the counter row counts the chunks that took the correcting path
+(``_qmatmul_call`` returns the raw rows); zero weight flags for a call
+mean none did. Where ``rc`` is not a multiple of 32 (only at small K),
+every chunk takes the per-byte path and ``_SLOW`` stays 0.
+
 Tiles are clamped to what the TPU compiler accepts (the last two block
 dims divisible by (8, 128), or equal to the array dims): BN a multiple of
 128 or all of N, BK a multiple of 128 or all of K, BM a multiple of 32 or
@@ -85,8 +105,12 @@ ABFT_ATOL = 1e-6
 ABFT_RTOL = 1e-4
 
 LANES = 128
-# count lanes of the per-N-strip counter row
-_SINGLE, _DOUBLE, _COLS = 0, 1, 2
+# count lanes of the per-N-strip counter row: corrected and detected
+# blocks, column-checksum mismatches, chunks that took the correcting path
+_SINGLE, _DOUBLE, _COLS, _SLOW = 0, 1, 2, 3
+# most weight-tile rows the fast decode pass takes per loop step: chunks
+# unrolled into one step let the scheduler overlap their dependence chains
+_FAST_ROWS = 2560
 
 
 def _legal(t: int, full: int, align: int) -> int:
@@ -189,31 +213,92 @@ def _kernel(*refs, dims, path, has_bias, has_clamp, with_abft, fault_bits,
         table = table_ref[...]
         block_lane = jnp.logical_and(colv, (col & 7) == 7)
 
-        def chunk(r, carry):
-            r0 = pl.multiple_of(r * rc, rc)
-            x = w_ref[pl.ds(r0, rc), :].astype(jnp.int32)
-            dec, single, double = ecc_decode.decode_lanes(x, table)
-            q = ecc_decode.signed(dec)
+        def rows(r0):
+            return kk * bk + r0 + jax.lax.broadcasted_iota(jnp.int32,
+                                                           (rc, 1), 0)
+
+        def store(r0, q):
+            """Write a chunk's int8 values (any int dtype) to its slot."""
             if path == "float":   # the strip holds the dequantized weights
                 w = (q.astype(jnp.float32) * scale_ref[0, 0]
                      ).astype(wdec_ref.dtype)
             else:
                 w = q.astype(jnp.int8)
             wdec_ref[pl.ds(pl.multiple_of(kk * bk + r0, rc), rc), :] = w
-            row = kk * bk + r0 + jax.lax.broadcasted_iota(jnp.int32,
-                                                          (rc, 1), 0)
-            valid = jnp.logical_and(row < k, block_lane)
-            s_cnt, d_cnt = carry
+
+        def correct(r, carry):
+            """Per-byte decode of chunk ``r`` with correction; flags
+            counted on real blocks."""
+            r0 = pl.multiple_of(r * rc, rc)
+            x = w_ref[pl.ds(r0, rc), :].astype(jnp.int32)
+            dec, single, double = ecc_decode.decode_lanes(x, table)
+            store(r0, ecc_decode.signed(dec))
+            valid = jnp.logical_and(rows(r0) < k, block_lane)
+            s_cnt, d_cnt, n_slow = carry
             s_cnt += jnp.sum(jnp.logical_and(single, valid).astype(
                 jnp.int32), axis=0, keepdims=True)
             d_cnt += jnp.sum(jnp.logical_and(double, valid).astype(
                 jnp.int32), axis=0, keepdims=True)
-            return s_cnt, d_cnt
+            return s_cnt, d_cnt, n_slow
+
+        def packed(r0):
+            """Chunk words (four rows of one lane per int32) and their
+            block syndromes, padding rows past K zeroed."""
+            words = pltpu.bitcast(w_ref[pl.ds(r0, rc), :], jnp.int32)
+            syn = ecc_decode.syndrome_words(words, table)
+            if k % bk:   # a row mask packed the way the words are
+                real = jnp.broadcast_to(jnp.where(rows(r0) < k, -1, 0),
+                                        (rc, bn))
+                syn = syn & pltpu.bitcast(real.astype(jnp.int8), jnp.int32)
+            return words, syn
+
+        def flagged(syn):
+            """Does a real block (last lane, inside N) have a syndrome?
+            Syndrome bytes are below 128, so the words are >= 0."""
+            return jnp.max(jnp.where(block_lane, syn, 0)) > 0
+
+        def restore(r, acc):
+            """Fast pass: store the sign-restored chunk, OR its syndromes
+            into ``acc``."""
+            r0 = pl.multiple_of(r * rc, rc)
+            words, syn = packed(r0)
+            store(r0, pltpu.bitcast(ecc_decode.restore_words(words),
+                                    jnp.int8))
+            return acc | syn
+
+        def recheck(r, carry):
+            """Redo chunk ``r`` per byte if a real block of it is faulty."""
+            def redo(carry):
+                s_cnt, d_cnt, n_slow = correct(r, carry)
+                return s_cnt, d_cnt, n_slow + 1
+
+            _, syn = packed(pl.multiple_of(r * rc, rc))
+            return jax.lax.cond(flagged(syn), redo, lambda c: c, carry)
 
         zero = jnp.zeros((1, bn), jnp.int32)
-        s_cnt, d_cnt = jax.lax.fori_loop(0, bk // rc, chunk, (zero, zero))
+        counts = (zero, zero, jnp.int32(0))
+        n_ch = bk // rc
+        if rc % 32:   # not whole words: per byte throughout
+            counts = jax.lax.fori_loop(0, n_ch, correct, counts)
+        else:
+            per_step = max(u for u in range(1, n_ch + 1)
+                           if n_ch % u == 0 and u * rc <= _FAST_ROWS)
+
+            def restore_step(t, acc):
+                for u in range(per_step):
+                    acc = restore(t * per_step + u, acc)
+                return acc
+
+            acc = jax.lax.fori_loop(0, n_ch // per_step, restore_step,
+                                    jnp.zeros((rc // 4, bn), jnp.int32))
+            counts = jax.lax.cond(
+                flagged(acc),
+                lambda c: jax.lax.fori_loop(0, n_ch, recheck, c),
+                lambda c: c, counts)
+        s_cnt, d_cnt, n_slow = counts
         bump(_SINGLE, jnp.sum(s_cnt, axis=1, keepdims=True))
         bump(_DOUBLE, jnp.sum(d_cnt, axis=1, keepdims=True))
+        bump(_SLOW, n_slow)
 
     a = a_ref[...]  # (BM, BK)
     if k % bk:  # mask activation columns past K so edge tiles contribute 0
@@ -382,6 +467,29 @@ def ecc_qmatmul(a: jnp.ndarray, w_enc: jnp.ndarray, w_scale=None, *,
     per-call decode work is ceil(N/BN) * ceil(K/BK) tiles — independent of
     M.
     """
+    out, counts, rows = _qmatmul_call(
+        a, w_enc, w_scale, a_scale=a_scale, bias=bias, out_dtype=out_dtype,
+        bm=bm, bn=bn, bk=bk, with_abft=with_abft, clamp=clamp,
+        fault_bits=fault_bits)
+    counts = counts.sum(axis=(0, 1))
+    outs = (out,)
+    if with_flags:
+        outs += (counts[_SINGLE:_DOUBLE + 1],)
+    if rows is not None:
+        # per-row (mismatch, clamp-hit) counts summed over N strips, plus
+        # the column-check mismatch total (not row-attributable).
+        outs += ((rows.sum(axis=0), counts[_COLS]),)
+    return outs if len(outs) > 1 else out
+
+
+def _qmatmul_call(a, w_enc, w_scale=None, *, a_scale=None, bias=None,
+                  out_dtype=None, bm=128, bn=128, bk=0, with_abft=False,
+                  clamp=None, fault_bits=0):
+    """The kernel call behind :func:`ecc_qmatmul`, same arguments. Returns
+    ``(out, counts, rows)``: ``counts`` the raw ``(ceil(N/BN), 1, 128)``
+    int32 counter rows, one per N strip (lanes ``_SINGLE``, ``_DOUBLE``,
+    ``_COLS``, ``_SLOW``), and ``rows`` the per-strip ``(ceil(N/BN), M, 2)``
+    ABFT/clamp row counts, or None when neither guard is on."""
     m, k = a.shape
     k2, n = w_enc.shape
     assert k == k2 and n % 8 == 0, (a.shape, w_enc.shape)
@@ -465,12 +573,4 @@ def ecc_qmatmul(a: jnp.ndarray, w_enc: jnp.ndarray, w_scale=None, *,
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=platform.interpret(),
     )(*inputs)
-    out, counts = res[0], res[1].sum(axis=(0, 1))
-    outs = (out,)
-    if with_flags:
-        outs += (counts[_SINGLE:_DOUBLE + 1],)
-    if track:
-        # per-row (mismatch, clamp-hit) counts summed over N strips, plus
-        # the column-check mismatch total (not row-attributable).
-        outs += ((res[2].sum(axis=0), counts[_COLS]),)
-    return outs if len(outs) > 1 else out
+    return res[0], res[1], (res[2] if track else None)

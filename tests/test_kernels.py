@@ -4,7 +4,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from jax.experimental.pallas import tpu as pltpu
+
 from repro.core import ecc, faults
+from repro.kernels import ecc_decode as codec
+from repro.kernels import ecc_qmatmul as qmm
 from repro.kernels import ops, ref
 from repro.kernels.ecc_decode import ecc_decode
 from repro.kernels.ecc_qmatmul import ecc_qmatmul
@@ -79,6 +83,130 @@ def test_ecc_qmatmul_corrects_faults():
     out = ecc_qmatmul(jnp.asarray(a), jnp.asarray(f), bm=64, bn=128, bk=128)
     plain = a.astype(np.int32) @ wq.astype(np.int32)
     assert (np.asarray(out) == plain).all()
+
+
+def _encode(wq):
+    k, n = wq.shape
+    return np.asarray(ecc.encode64(jnp.asarray(
+        wq.view(np.uint8).reshape(k, n // 8, 8)))).reshape(k, n)
+
+
+def _flip(enc, flips):
+    f = enc.copy()
+    for r, c, bits in flips:
+        f[r, c] ^= np.uint8(bits)
+    return f
+
+
+@pytest.mark.parametrize("faulty", [False, True], ids=["clean", "faulty"])
+def test_packed_word_codec_matches_decode_lanes(faulty):
+    """syndrome_words/restore_words on a tile bitcast to int32 words give
+    decode_lanes' block syndromes and (on clean blocks) its decoded
+    bytes."""
+    rng = np.random.default_rng(11)
+    r, w = 64, 256
+    enc = _encode(_wot_weights(rng, (r, w)))
+    if faulty:
+        enc = _flip(enc, [(0, 0, 0x01), (9, 77, 0x30), (33, 200, 0x80),
+                          (63, 255, 0x40)])
+    table = jnp.asarray(codec.code_table(w))
+
+    @jax.jit
+    def run(enc):
+        x = enc.astype(jnp.int32)
+        dec, single, double = codec.decode_lanes(x, table)
+        words = pltpu.bitcast(enc, jnp.int32)
+        assert words.shape == (r // 4, w)
+        syn = pltpu.bitcast(codec.syndrome_words(words, table), jnp.uint8)
+        restored = pltpu.bitcast(codec.restore_words(words), jnp.uint8)
+        return (dec, single | double, codec._syndrome(x, table), syn,
+                restored)
+
+    dec, flagged, syn_bytes, syn, restored = map(np.asarray,
+                                                 run(jnp.asarray(enc)))
+    # each block's syndrome sits on its last lane
+    assert np.array_equal(syn[:, 7::8], syn_bytes[:, 7::8])
+    assert np.array_equal(syn[:, 7::8] != 0, flagged[:, 7::8])
+    clean = ~flagged
+    assert clean.sum() > 0 and faulty == (not clean.all())
+    assert np.array_equal(restored[clean], dec[clean])
+
+
+# (k, n, bk, flips): bn is 128 throughout, m 16
+_QMM_CASES = {
+    "clean": (256, 256, 128, []),
+    "single": (256, 256, 128, [(5, 9, 0x04)]),
+    "double": (256, 256, 128, [(130, 40, 0x06)]),
+    "two_strips": (256, 256, 128, [(5, 9, 0x10), (200, 250, 0x80)]),
+    "fallback": (100, 256, 0, [(5, 9, 0x04), (99, 255, 0x03)]),
+    "kedge_clean": (320, 200, 128, []),
+    "kedge_single": (320, 200, 128, [(319, 199, 0x01)]),
+}
+_PAD = 0x5A   # a byte whose 8-byte block has a nonzero syndrome
+
+
+@pytest.fixture
+def garbage_padding(monkeypatch):
+    """Interpret-mode edge tiles read this byte past the weight array (it
+    is uint8 zero otherwise, whose syndrome is zero), as a TPU reads
+    whatever lies there."""
+    from jax._src.pallas import primitives
+    orig = primitives.uninitialized_value
+
+    def fill(shape, dtype):
+        if dtype == jnp.uint8:
+            return jnp.full(shape, _PAD, dtype)
+        return orig(shape, dtype)
+    monkeypatch.setattr(primitives, "uninitialized_value", fill)
+    jax.clear_caches()
+    yield
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("case", sorted(_QMM_CASES))
+@pytest.mark.parametrize("path", ["float", "int8", "requant", "abft"])
+def test_packed_decode_bit_identical(garbage_padding, path, case):
+    """Every path of the fused matmul, clean and faulty weights, packed and
+    per-byte chunks: output and flags equal the decode-then-matmul
+    reference, and the ``_SLOW`` lane counts exactly the chunks holding a
+    real faulty block (none on a row chunk that is not whole words)."""
+    _, s_pad, d_pad = ecc.decode64(jnp.full((1, 8), _PAD, jnp.uint8))
+    assert bool(s_pad[0] | d_pad[0])
+    k, n, bk, flips = _QMM_CASES[case]
+    m, bm, bn = 16, 16, 128
+    rng = np.random.default_rng(k + n + len(flips))
+    enc = _flip(_encode(_wot_weights(rng, (k, n))), flips)
+    a = rng.integers(-8, 9, size=(m, k)).astype(np.int8)
+    acc = np.asarray(ref.ecc_qmatmul_ref(jnp.asarray(a), jnp.asarray(enc)))
+    _, single, double = ecc.decode64(jnp.asarray(enc).reshape(k, n // 8, 8))
+    want_flags = [int(single.sum()), int(double.sum())]
+    scale = jnp.float32(2.0 ** -6)   # exact in bf16: any sum order agrees
+    kw = dict(bm=bm, bn=bn, bk=bk)
+    if path == "float":
+        args = (jnp.asarray(a).astype(jnp.bfloat16), enc, scale)
+        want = acc.astype(np.float32) * np.float32(2.0 ** -6)
+    elif path == "requant":
+        kw["a_scale"] = jnp.asarray(rng.uniform(0.5, 2, (m, 1)), jnp.float32)
+        args = (jnp.asarray(a), enc, scale)
+        want = np.asarray((jnp.asarray(acc).astype(jnp.float32)
+                           * (kw["a_scale"] * scale)).astype(jnp.bfloat16))
+    else:
+        args, kw["with_abft"] = (jnp.asarray(a), enc), path == "abft"
+        want = acc
+    out, counts, rows = qmm._qmatmul_call(*args, **kw)
+    counts = np.asarray(counts).sum(axis=(0, 1))
+    assert np.array_equal(np.asarray(out), want)
+    assert counts[qmm._SINGLE:qmm._DOUBLE + 1].tolist() == want_flags
+    if path == "abft":
+        assert not np.asarray(rows).any() and counts[qmm._COLS] == 0
+    # the public wrapper returns the same numbers
+    out2, flags2 = ecc_qmatmul(*args, **kw, with_flags=True)[:2]
+    assert np.array_equal(np.asarray(out2), np.asarray(out))
+    assert np.asarray(flags2).tolist() == want_flags
+    rc = qmm._row_chunk(qmm._legal(0 if path == "requant" else bk, k, 128))
+    dirty = {(c // bn, r // rc) for r, c, _ in flips}
+    assert (rc % 32 != 0) == (case == "fallback")
+    assert counts[qmm._SLOW] == (0 if rc % 32 else len(dirty))
 
 
 @pytest.mark.parametrize("nblk", [64, 1000, 4096])

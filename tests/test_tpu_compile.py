@@ -3,11 +3,12 @@
 Interpret mode (the rest of the suite) cannot see what Mosaic refuses:
 block shapes off the (8, 128) tiling, unsigned reductions, narrow dots.
 These tests compile each kernel for a described (not attached) v5e chip —
-Qwen1.5-4B widths (d_model 2560, d_ff 6912, 20 KV heads of 128) plus one
-GQA attention case at Minitron-4B's 8 KV heads, 3 queries each — and check
-that the program holds the Mosaic kernel. Nothing runs; the topology is
-described inside a fixture so that importing this file touches no TPU
-library.
+Qwen1.5-4B widths (d_model 2560, d_ff 6912, 20 KV heads of 128),
+DeepSeek-LLM-7B widths (d_model 4096, d_ff 11008, a 102400-wide output
+head, 32 KV heads of 128), and one GQA attention case at Minitron-4B's 8
+KV heads, 3 queries each — and check that the program holds the Mosaic
+kernel. Nothing runs; the topology is described inside a fixture so that
+importing this file touches no TPU library.
 """
 import jax
 import jax.numpy as jnp
@@ -18,6 +19,7 @@ from repro.kernels import ecc_decode, ecc_encode, paged_attention, platform
 from repro.kernels.ecc_qmatmul import ecc_qmatmul
 
 D_MODEL, D_FF, BATCH, SEQ, HD = 2560, 6912, 4, 512, 128
+DS_MODEL, DS_FF, DS_VOCAB = 4096, 11008, 102400
 
 
 @pytest.fixture(scope="module")
@@ -60,7 +62,9 @@ def _mosaic(hlo: str) -> bool:
     return "tpu_custom_call" in hlo
 
 
-@pytest.mark.parametrize("k,n", [(D_MODEL, D_FF), (D_FF, D_MODEL)])
+@pytest.mark.parametrize("k,n", [(D_MODEL, D_FF), (D_FF, D_MODEL),
+                                 (DS_MODEL, DS_FF), (DS_FF, DS_MODEL),
+                                 (DS_MODEL, DS_VOCAB)])
 @pytest.mark.parametrize("path", ["float", "requant", "float-abft",
                                   "requant-abft"])
 def test_ecc_qmatmul_compiles(compile_tpu, path, k, n):
@@ -78,8 +82,9 @@ def test_ecc_qmatmul_compiles(compile_tpu, path, k, n):
 
 
 @pytest.mark.parametrize("kernel", ["strip", "chunked"])
-@pytest.mark.parametrize("kv,rep", [(20, 1), (8, 3)],
-                         ids=["qwen1.5-4b", "minitron-4b-gqa"])
+@pytest.mark.parametrize("kv,rep", [(20, 1), (8, 3), (32, 1)],
+                         ids=["qwen1.5-4b", "minitron-4b-gqa",
+                              "deepseek-llm-7b"])
 def test_page_attention_compiles(compile_tpu, kernel, kv, rep):
     attend = (paged_attention.fused_page_attention if kernel == "strip"
               else paged_attention.chunked_page_attention)

@@ -31,7 +31,8 @@ check for zero needs no broadcast back), and :func:`restore_words` the
 packed sign restore. A tile whose syndromes are all zero decodes to
 exactly what :func:`decode_lanes` returns for it, with no flags, so only a
 tile with a nonzero syndrome needs the per-byte correction. The fused
-matmul takes this path (``ecc_qmatmul``).
+matmul (``ecc_qmatmul``) and the paged-attention kernels
+(``paged_attention``, in-place KV strips) take this path.
 
 The standalone kernels take ``(..., nb, 8)`` blocks but run on the 2-D
 byte plane those blocks view (:func:`plane`), so every vreg lane carries

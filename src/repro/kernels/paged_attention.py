@@ -42,8 +42,31 @@ The page-table gather itself (pool -> (B, S, ...) strips) stays in XLA
 before the ``pallas_call``, as does the transpose to (B, KV, S, hd) that
 makes each grid cell's strip a TPU-legal block: gathers are layout
 transforms XLA schedules well, while the kernels own everything that must
-not leave VMEM decoded. Blocks decode with the lane-dense codec shared with
-the fused matmul (``ecc_decode.decode_lanes``).
+not leave VMEM decoded.
+
+Both kernels decode a grid step's K and V strips with the lane-dense codec
+shared with the fused matmul: the 8-byte ECC blocks run along ``hd``, the
+lane axis. Under the in-place scheme, where the strip's row count is a
+multiple of 32 (every serving shape), a fast pass bitcasts
+(``pltpu.bitcast``) each uint8 ``(s, hd)`` strip to ``(s/4, hd)`` int32
+words, four rows of one lane per word, computes every block's syndrome
+four bytes per op (``ecc_decode.syndrome_words``), stores the packed sign
+restore (``restore_words``) bitcast back to int8 in a VMEM scratch strip,
+and ORs the syndromes of valid blocks (rows ``<= pos``, masked by a row
+mask packed the way the words are) of both strips into one accumulator.
+Only if that accumulator is nonzero (one branch per grid step) does the
+correcting pass re-check the strips in 32-row chunks and run the per-byte
+``ecc_decode.decode_lanes`` path, with its flag counts, on each chunk that
+holds a faulty valid block, overwriting what the fast pass stored there.
+A strip whose valid syndromes are all zero decodes to exactly its
+sign-restored bytes with no flags, so values and flags are bit-identical
+to a per-byte decode of every byte; bytes past ``pos`` may stay
+uncorrected, but they are finite and their softmax weight is exactly 0.
+Lane ``_SLOW`` of a grid cell's flag row counts the chunks that took the
+correcting pass (:func:`_fused_call` and :func:`_chunked_call` return the
+raw rows). Other row counts, and the faulty and parity-zero schemes,
+decode every byte per byte.
+
 Flags (corrected, DUE) are masked to valid (``<= pos``) tokens inside the
 kernel, summed per (batch, kv-head) cell, and reduced outside — per
 batch row (``per_slot=True``, for per-request fault attribution) or to
@@ -68,6 +91,12 @@ KV_SCHEMES = ("faulty", "parity-zero", "in-place")
 # gathered working set must fit inside, and the denominator of the
 # structural crossover recorded by benchmarks/kernel_bench.py.
 VMEM_BUDGET_BYTES = 16 * 2 ** 20
+
+# lanes of a grid cell's (1, 128) flag row: corrected and detected blocks
+# over valid tokens, row chunks that took the correcting pass
+_COR, _DUE, _SLOW = 0, 1, 2
+# rows the correcting pass re-checks at a time: one (32, 128) uint8 tile
+_CHUNK = 32
 
 
 def _count(hit, valid_col):
@@ -111,44 +140,109 @@ def _decode_strip(enc, ch, valid_col, table, *, scheme):
             _count(jnp.logical_and(double, block_lane), valid_col))
 
 
-def _flag_row(cor, due):
+def _flag_row(cor, due, slow):
     """(1, 1) counts -> the (1, 128) lane row a grid cell writes."""
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, 128), 1)
-    return jnp.where(lane == 0, cor, jnp.where(lane == 1, due, 0))
+    return jnp.where(lane == _COR, cor, jnp.where(
+        lane == _DUE, due, jnp.where(lane == _SLOW, slow, 0)))
 
 
-def _unpack(refs, has_checks):
+def _unpack(refs, has_checks, packed):
     """Positional kernel refs -> dict (the check planes ride only with the
-    parity-zero scheme)."""
+    parity-zero scheme, the decoded-strip scratch only with the packed
+    pass, after the kernel's own scratch)."""
     it = iter(refs)
     names = ["q", "ke"] + (["kch"] if has_checks else []) + \
         ["ksc", "ve"] + (["vch"] if has_checks else []) + \
         ["vsc", "pos", "table", "o", "flags"]
     r = {name: next(it) for name in names}
     r["scratch"] = list(it)
+    if packed:
+        r["kdec"], r["vdec"] = r["scratch"][-2:]
+        del r["scratch"][-2:]
     return r
 
 
-def _strips(r, valid_col, scheme):
-    """Decode this grid cell's K and V strips -> (kq, vq, cor, due)."""
+def _packed_strips(r, first, pos, table):
+    """In-place decode of this grid step's K and V strips, four bytes per
+    int32 op, into the ``kdec``/``vdec`` int8 scratch; the per-byte path
+    only on 32-row chunks whose valid blocks have a nonzero syndrome.
+    Rows ``first + i`` with ``i`` the strip row are valid ``<= pos``.
+    Returns (kq, vq, cor, due, slow)."""
+    s, hd = r["kdec"].shape
+    strips = (("ke", r["kdec"]), ("ve", r["vdec"]))
+    block_lane = (jax.lax.broadcasted_iota(jnp.int32, (1, hd), 1) & 7) == 7
+
+    def valid(r0, n):
+        return first + r0 + jax.lax.broadcasted_iota(jnp.int32, (n, 1),
+                                                     0) <= pos
+
+    def dirty(syn, r0, n):
+        """Does a valid block (last lane, row <= pos) have a syndrome? The
+        row mask is packed the way the words are; syndrome bytes are below
+        128, so the words are >= 0."""
+        mask = jnp.broadcast_to(jnp.where(valid(r0, n), -1, 0), (n, hd))
+        syn = syn & pltpu.bitcast(mask.astype(jnp.int8), jnp.int32)
+        return jnp.max(jnp.where(block_lane, syn, 0)) > 0
+
+    acc = jnp.zeros((s // 4, hd), jnp.int32)
+    for name, dec in strips:
+        words = pltpu.bitcast(r[name][0, 0], jnp.int32)
+        dec[...] = pltpu.bitcast(ecc_decode.restore_words(words), jnp.int8)
+        acc = acc | ecc_decode.syndrome_words(words, table)
+
+    def recheck(c, counts):
+        """Redo chunk ``c`` of each strip per byte if a valid block of it
+        is faulty."""
+        r0 = pl.multiple_of(c * _CHUNK, _CHUNK)
+        for name, dec in strips:
+            enc = r[name][0, 0, pl.ds(r0, _CHUNK), :]
+
+            def redo(counts, enc=enc, dec=dec):
+                q, cor, due = _decode_strip(enc, None, valid(r0, _CHUNK),
+                                            table, scheme="in-place")
+                dec[pl.ds(r0, _CHUNK), :] = q.astype(jnp.int8)
+                return counts[0] + cor, counts[1] + due, counts[2] + 1
+
+            syn = ecc_decode.syndrome_words(
+                pltpu.bitcast(enc, jnp.int32), table)
+            counts = jax.lax.cond(dirty(syn, r0, _CHUNK), redo,
+                                  lambda c: c, counts)
+        return counts
+
+    zero = jnp.zeros((1, 1), jnp.int32)
+    counts = jax.lax.cond(
+        dirty(acc, 0, s),
+        lambda c: jax.lax.fori_loop(0, s // _CHUNK, recheck, c),
+        lambda c: c, (zero, zero, zero))
+    return (r["kdec"][...], r["vdec"][...], *counts)
+
+
+def _strips(r, first, pos, scheme):
+    """Decode this grid step's K and V strips, rows ``first + i`` valid
+    ``<= pos`` -> (kq, vq, cor, due, slow): int values as any int dtype,
+    (1, 1) counts."""
     table = r["table"][...]
+    if "kdec" in r:
+        return _packed_strips(r, first, pos, table)
+    s = r["ke"].shape[2]
+    valid_col = first + jax.lax.broadcasted_iota(jnp.int32, (s, 1), 0) <= pos
     ch = lambda name: r[name][0, 0] if name in r else None
     kq, kcor, kdue = _decode_strip(r["ke"][0, 0], ch("kch"), valid_col,
                                    table, scheme=scheme)
     vq, vcor, vdue = _decode_strip(r["ve"][0, 0], ch("vch"), valid_col,
                                    table, scheme=scheme)
-    return kq, vq, kcor + vcor, kdue + vdue
+    return kq, vq, kcor + vcor, kdue + vdue, jnp.zeros((1, 1), jnp.int32)
 
 
-def _kernel(*refs, scheme, s, has_checks):
-    r = _unpack(refs, has_checks)
+def _kernel(*refs, scheme, s, has_checks, packed):
+    r = _unpack(refs, has_checks, packed)
     qb = r["q"][0, 0]                                  # (rep, hd)
     hd = qb.shape[-1]
     pos = r["pos"][pl.program_id(0)]
     # 2-D iotas throughout (Mosaic rejects rank-1 iota outside interpret)
-    valid_col = jax.lax.broadcasted_iota(jnp.int32, (s, 1), 0) <= pos
     valid_row = jax.lax.broadcasted_iota(jnp.int32, (1, s), 1) <= pos
-    kq, vq, cor, due = _strips(r, valid_col, scheme)
+    kq, vq, cor, due, slow = _strips(r, 0, pos, scheme)
     cdt = qb.dtype
     kf = (kq.astype(jnp.float32) * r["ksc"][0]).astype(cdt)   # (s, hd)
     vf = (vq.astype(jnp.float32) * r["vsc"][0]).astype(cdt)
@@ -164,13 +258,13 @@ def _kernel(*refs, scheme, s, has_checks):
     r["o"][0, 0] = jax.lax.dot_general(
         pr, vf, dimension_numbers=(((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32).astype(r["o"].dtype)
-    r["flags"][0, 0] = _flag_row(cor, due)
+    r["flags"][0, 0] = _flag_row(cor, due, slow)
 
 
 def _reduce_flags(flags, per_slot: bool):
     """(b, kv, 1, 128) in-grid flag rows -> (2, b) per-slot rows or (2,)
     batch totals."""
-    flags = flags[:, :, 0, :2]
+    flags = flags[:, :, 0, _COR:_DUE + 1]
     if per_slot:
         return flags.sum(axis=1).T                     # (2, b)
     return flags.sum(axis=(0, 1))                      # (2,)
@@ -181,11 +275,15 @@ def _call(kernel, q, ke, kch, ksc, ve, vch, vsc, pos, *, scheme, sblk,
     """Shared pallas_call plumbing of both kernels. Strips are gathered in
     XLA as (B, S, KV, x) and handed over as (B, KV, S, x) so each grid
     cell's (S, hd) strip is a block whose last two dims are whole array
-    dims; scales ride as (B, S, 1) columns, positions in SMEM."""
+    dims; scales ride as (B, S, 1) columns, positions in SMEM. Returns
+    the (B, KV, rep, hd) output and the raw (B, KV, 1, 128) flag rows."""
     b, h, _, hd = q.shape
     kv = ke.shape[2]
     rep = h // kv
     has_checks = scheme == "parity-zero"
+    packed = scheme == "in-place" and sblk % _CHUNK == 0
+    if packed:   # the decoded K and V strips
+        scratch = [*scratch, *[pltpu.VMEM((sblk, hd), jnp.int8)] * 2]
     if has_checks and kch is None:
         kch = jnp.zeros((*ke.shape[:3], hd // 8), jnp.uint8)
         vch = jnp.zeros((*ve.shape[:3], hd // 8), jnp.uint8)
@@ -208,7 +306,8 @@ def _call(kernel, q, ke, kch, ksc, ve, vch, vsc, pos, *, scheme, sblk,
     specs += [pl.BlockSpec(memory_space=pltpu.SMEM),
               pl.BlockSpec((8, hd), lambda *_: (0, 0))]
     return pl.pallas_call(
-        functools.partial(kernel, scheme=scheme, has_checks=has_checks),
+        functools.partial(kernel, scheme=scheme, has_checks=has_checks,
+                          packed=packed),
         grid=grid,
         in_specs=specs,
         out_specs=[pl.BlockSpec((1, 1, rep, hd), heads),
@@ -240,13 +339,22 @@ def fused_page_attention(q, ke, kch, ksc, ve, vch, vsc, pos, *,
     """
     if scheme not in KV_SCHEMES:
         raise ValueError(f"scheme {scheme!r}; one of {KV_SCHEMES}")
+    out, rows = _fused_call(q, ke, kch, ksc, ve, vch, vsc, pos,
+                            scheme=scheme)
+    return out, _reduce_flags(rows, per_slot)
+
+
+def _fused_call(q, ke, kch, ksc, ve, vch, vsc, pos, *, scheme):
+    """The kernel call behind :func:`fused_page_attention`. Returns ``(o,
+    rows)``: ``rows`` the raw ``(B, KV, 1, 128)`` int32 flag rows, one per
+    grid cell (lanes ``_COR``, ``_DUE``, ``_SLOW``)."""
     b, h, _, hd = q.shape
     s, kv = ke.shape[1], ke.shape[2]
-    out, flags = _call(
+    out, rows = _call(
         functools.partial(_kernel, s=s), q, ke, kch, ksc, ve, vch, vsc, pos,
         scheme=scheme, sblk=s, seq_block=lambda: 0, grid=(b, kv),
         semantics=("parallel", "parallel"))
-    return out.reshape(b, h, 1, hd), _reduce_flags(flags, per_slot)
+    return out.reshape(b, h, 1, hd), rows
 
 
 # ---------------------------------------------------------------------------
@@ -254,8 +362,8 @@ def fused_page_attention(q, ke, kch, ksc, ve, vch, vsc, pos, *,
 # ---------------------------------------------------------------------------
 
 
-def _chunked_kernel(*refs, scheme, chunk, nchunks, has_checks):
-    r = _unpack(refs, has_checks)
+def _chunked_kernel(*refs, scheme, chunk, nchunks, has_checks, packed):
+    r = _unpack(refs, has_checks, packed)
     m_ref, l_ref, acc_ref = r["scratch"]
     flags_ref = r["flags"]
     c = pl.program_id(2)
@@ -276,11 +384,9 @@ def _chunked_kernel(*refs, scheme, chunk, nchunks, has_checks):
     def _update():
         qb = r["q"][0, 0].astype(jnp.float32)          # (rep, hd)
         hd = qb.shape[-1]
-        valid_col = base + jax.lax.broadcasted_iota(
-            jnp.int32, (chunk, 1), 0) <= pos
         valid_row = base + jax.lax.broadcasted_iota(
             jnp.int32, (1, chunk), 1) <= pos
-        kq, vq, cor, due = _strips(r, valid_col, scheme)
+        kq, vq, cor, due, slow = _strips(r, base, pos, scheme)
         kf = kq.astype(jnp.float32) * r["ksc"][0]      # (chunk, hd)
         vf = vq.astype(jnp.float32) * r["vsc"][0]
         sc = jax.lax.dot_general(
@@ -299,7 +405,7 @@ def _chunked_kernel(*refs, scheme, chunk, nchunks, has_checks):
         m_ref[...] = jnp.broadcast_to(m_cur, m_ref.shape)
         acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
             p, vf, dimension_numbers=(((1,), (0,)), ((), ())))
-        flags_ref[0, 0] += _flag_row(cor, due)
+        flags_ref[0, 0] += _flag_row(cor, due, slow)
 
     @pl.when(c == nchunks - 1)
     def _finish():
@@ -332,6 +438,15 @@ def chunked_page_attention(q, ke, kch, ksc, ve, vch, vsc, pos, *,
         raise ValueError(f"scheme {scheme!r}; one of {KV_SCHEMES}")
     if chunk_tokens < 1:
         raise ValueError(f"chunk_tokens must be positive, got {chunk_tokens}")
+    out, rows = _chunked_call(q, ke, kch, ksc, ve, vch, vsc, pos,
+                              scheme=scheme, chunk_tokens=chunk_tokens)
+    return out, _reduce_flags(rows, per_slot)
+
+
+def _chunked_call(q, ke, kch, ksc, ve, vch, vsc, pos, *, scheme,
+                  chunk_tokens):
+    """The kernel call behind :func:`chunked_page_attention`; returns
+    ``(o, rows)`` as :func:`_fused_call` does."""
     b, h, _, hd = q.shape
     s, kv = ke.shape[1], ke.shape[2]
     rep = h // kv
@@ -344,7 +459,7 @@ def chunked_page_attention(q, ke, kch, ksc, ve, vch, vsc, pos, *,
         ke, kch, ve, vch = grow(ke), grow(kch), grow(ve), grow(vch)
         ksc, vsc = grow(ksc), grow(vsc)
     nc = (s + pad) // chunk
-    out, flags = _call(
+    out, rows = _call(
         functools.partial(_chunked_kernel, chunk=chunk, nchunks=nc),
         q, ke, kch, ksc, ve, vch, vsc, pos, scheme=scheme, sblk=chunk,
         seq_block=lambda c: c, grid=(b, kv, nc),
@@ -352,7 +467,7 @@ def chunked_page_attention(q, ke, kch, ksc, ve, vch, vsc, pos, *,
                  pltpu.VMEM((rep, 128), jnp.float32),   # running normalizer l
                  pltpu.VMEM((rep, hd), jnp.float32)],   # running accumulator
         semantics=("parallel", "parallel", "arbitrary"))
-    return out.reshape(b, h, 1, hd), _reduce_flags(flags, per_slot)
+    return out.reshape(b, h, 1, hd), rows
 
 
 # ---------------------------------------------------------------------------

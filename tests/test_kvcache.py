@@ -3,6 +3,7 @@ page codec fault behaviour, fused-vs-reference bit identity, the paged
 serving chain (prefill -> decode), live-pool injection and per-layer KV
 flags, KV fault campaigns, byte accounting, the plan-level KV knob, and
 the windowed-ring / ragged-length attention regressions it leans on."""
+import functools
 import os
 
 import jax
@@ -131,46 +132,96 @@ def test_page_quantization_error_bound():
 # ---------------------------------------------------------------------------
 
 
+# name -> (s, flips): a flip XORs ``bits`` into byte ``byte`` of token
+# ``t`` of kv head ``g`` of batch row ``bi`` of the K or V strip. The
+# positions are (s - 1, s // 2), so token 40 is past row 1's.
+_STRIP_CASES = {
+    "clean": (64, []),
+    "single": (64, [("k", 0, 1, 0, 3, 1 << 2)]),
+    "double": (64, [("v", 1, 5, 1, 2, 0b10010)]),
+    "past_pos": (64, [("k", 1, 40, 0, 3, 1 << 2)]),
+    "two_chunks": (64, [("k", 0, 3, 1, 1, 1), ("k", 0, 50, 1, 9, 1 << 6),
+                        ("v", 1, 10, 0, 4, 1 << 3)]),
+    "odd_s": (48, [("k", 0, 1, 0, 3, 1 << 2)]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_STRIP_CASES))
+@pytest.mark.parametrize("kernel", ["strip", "chunked"])
 @pytest.mark.parametrize("backend", ["xla", "pallas"])
 @pytest.mark.parametrize("scheme", kvcache.KV_SCHEMES)
-def test_fused_page_attention_bitexact(scheme, backend):
-    """The fused decode-at-use kernel must match decode-then-
-    ``decode_attention`` bit for bit — on clean strips AND on a faulted
-    strip (ragged positions, GQA rep=2), with identical flag counts. The
+def test_fused_page_attention_bitexact(scheme, backend, kernel, case):
+    """The fused decode-at-use strip kernel must match decode-then-
+    ``decode_attention`` bit for bit (the chunked kernel: within the fp64
+    oracle's tolerance), with identical flag counts, in total and per
+    slot (ragged positions, GQA rep=2), on clean and faulted strips. The
     reference is jitted, as in the serving paths: eager op-by-op execution
     materializes an intermediate bf16 rounding of the score dot that XLA
-    elides when the reference compiles as one program."""
+    elides when the reference compiles as one program.
+
+    The in-place packed pass (strips of a multiple of 32 rows) counts in
+    lane ``_SLOW`` of the raw flag rows the 32-row chunks it redid per
+    byte: exactly the dirty chunks among valid rows. A 48-token strip
+    (the chunked kernel given it whole) decodes per byte and counts 0."""
     rng = np.random.default_rng(4)
-    b, s, kv, hd, rep = 2, 32, 2, 16, 2
-    pol = kvcache.KVProtectionPolicy(scheme=scheme, backend=backend)
+    s, flips = _STRIP_CASES[case]
+    b, kv, hd, rep = 2, 2, 16, 2
+    pol = kvcache.KVProtectionPolicy(scheme=scheme, backend=backend,
+                                     per_slot_flags=True)
     q = _randn(rng, (b, kv * rep, 1, hd), jnp.bfloat16)
-    ke, kch, ksc = kvcache._encode_kv(_randn(rng, (b, s, kv, hd)), pol)
-    ve, vch, vsc = kvcache._encode_kv(_randn(rng, (b, s, kv, hd)), pol)
-    pos = jnp.asarray([s - 1, s // 2], jnp.int32)        # ragged batch
+    strips = {"k": kvcache._encode_kv(_randn(rng, (b, s, kv, hd)), pol),
+              "v": kvcache._encode_kv(_randn(rng, (b, s, kv, hd)), pol)}
+    pos = np.asarray([s - 1, s // 2], np.int32)        # ragged batch
+    flat = {name: np.asarray(enc[0]).copy() for name, enc in strips.items()}
+    slow = np.zeros((b, kv), np.int32)
+    expect = np.zeros((2, b), np.int32)     # (corrected, DUE) per slot
+    for name, bi, t, g, byte, bits in flips:
+        flat[name][bi, t, g, byte] ^= bits
+        n = bin(bits).count("1")
+        if t > pos[bi] or scheme == "faulty":
+            continue
+        if scheme == "in-place":            # one flip per block
+            expect[n - 1, bi] += 1
+        elif n % 2:                         # parity sees odd flips only
+            expect[0, bi] += 1
+    for name, bi, g, c in {(name, bi, g, t // 32)
+                           for name, bi, t, g, *_ in flips if t <= pos[bi]}:
+        slow[bi, g] += 1
+    ke, kch, ksc = jnp.asarray(flat["k"]), *strips["k"][1:]
+    ve, vch, vsc = jnp.asarray(flat["v"]), *strips["v"][1:]
+    args = (q, ke, kch, ksc, ve, vch, vsc, jnp.asarray(pos))
 
-    flat = np.asarray(ke).copy()
-    flat[0, 1, 0, 3] ^= 1 << 2          # fault in a token valid for seq 0
-    ke = jnp.asarray(flat)
+    o_r, cor, due = jax.jit(lambda *a: kvcache._reference_paged_attention(
+        *a, pol))(*args)
+    if kernel == "strip":
+        attend = functools.partial(paged_attention.fused_page_attention,
+                                   scheme=scheme)
+        raw = functools.partial(paged_attention._fused_call, scheme=scheme)
+    else:  # 32-token chunks, but a 48-token strip in one chunk
+        chunk = 32 if s % 32 == 0 else s
+        attend = functools.partial(paged_attention.chunked_page_attention,
+                                   scheme=scheme, chunk_tokens=chunk)
+        raw = functools.partial(paged_attention._chunked_call,
+                                scheme=scheme, chunk_tokens=chunk)
+    o_f, fl_f = attend(*args)
+    if kernel == "strip":
+        assert np.array_equal(np.asarray(o_f), np.asarray(o_r))
+    else:
+        oracle = paged_attention.oracle_page_attention(*args, scheme=scheme)
+        tol = 0.02 * (np.abs(oracle).max() + 1e-6)
+        assert np.abs(np.asarray(o_f, np.float64) - oracle).max() <= tol
+    assert np.array_equal(np.stack([cor, due]), expect)
+    assert np.array_equal(np.asarray(fl_f), expect.sum(axis=1))
 
-    o_f, fl_f = paged_attention.fused_page_attention(
-        q, ke, kch, ksc, ve, vch, vsc, pos, scheme=scheme)
-    reference = jax.jit(lambda *a: kvcache._reference_paged_attention(
-        *a, pol))
-    o_r, cor, due = reference(q, ke, kch, ksc, ve, vch, vsc, pos)
-    assert np.array_equal(np.asarray(o_f), np.asarray(o_r))
-    assert (int(fl_f[0]), int(fl_f[1])) == (int(cor), int(due))
-    if scheme != "faulty":
-        assert int(cor) == 1
-
-    # per-slot rows: same output, flags resolved per batch row with the
-    # injected fault attributed to sequence 0 only
-    o_p, fl_p = paged_attention.fused_page_attention(
-        q, ke, kch, ksc, ve, vch, vsc, pos, scheme=scheme, per_slot=True)
+    # per-slot rows: same output, flags resolved per batch row
+    o_p, fl_p = attend(*args, per_slot=True)
     assert np.array_equal(np.asarray(o_p), np.asarray(o_f))
-    assert fl_p.shape == (2, b)
-    assert np.array_equal(np.asarray(fl_p).sum(axis=1), np.asarray(fl_f))
-    if scheme != "faulty":
-        assert int(fl_p[0, 0]) == 1 and int(fl_p[0, 1]) == 0
+    assert np.array_equal(np.asarray(fl_p), expect)
+
+    _, rows = jax.jit(raw)(*args)
+    packed = scheme == "in-place" and s % 32 == 0
+    assert np.array_equal(np.asarray(rows)[:, :, 0, paged_attention._SLOW],
+                          slow if packed else 0 * slow)
 
 
 # ---------------------------------------------------------------------------

@@ -19,6 +19,7 @@ from repro.kernels import ecc_decode, ecc_encode, paged_attention, platform
 from repro.kernels.ecc_qmatmul import ecc_qmatmul
 
 D_MODEL, D_FF, BATCH, SEQ, HD = 2560, 6912, 4, 512, 128
+CELL_SEQ = 384   # the serving benchmark's strip length (max_len)
 DS_MODEL, DS_FF, DS_VOCAB = 4096, 11008, 102400
 
 
@@ -82,18 +83,21 @@ def test_ecc_qmatmul_compiles(compile_tpu, path, k, n):
 
 
 @pytest.mark.parametrize("kernel", ["strip", "chunked"])
-@pytest.mark.parametrize("kv,rep", [(20, 1), (8, 3), (32, 1)],
+@pytest.mark.parametrize("kv,rep,seq", [(20, 1, SEQ), (8, 3, SEQ),
+                                        (32, 1, SEQ), (20, 1, CELL_SEQ),
+                                        (32, 1, CELL_SEQ)],
                          ids=["qwen1.5-4b", "minitron-4b-gqa",
-                              "deepseek-llm-7b"])
-def test_page_attention_compiles(compile_tpu, kernel, kv, rep):
+                              "deepseek-llm-7b", "qwen1.5-4b-384",
+                              "deepseek-llm-7b-384"])
+def test_page_attention_compiles(compile_tpu, kernel, kv, rep, seq):
     attend = (paged_attention.fused_page_attention if kernel == "strip"
               else paged_attention.chunked_page_attention)
     fn = lambda q, ke, ksc, ve, vsc, pos: attend(q, ke, None, ksc, ve, None,
                                                  vsc, pos)
-    strip = ((BATCH, SEQ, kv, HD), jnp.uint8)
+    strip = ((BATCH, seq, kv, HD), jnp.uint8)
     hlo = compile_tpu(fn, ((BATCH, kv * rep, 1, HD), jnp.bfloat16), strip,
-                      ((BATCH, SEQ), jnp.float32), strip,
-                      ((BATCH, SEQ), jnp.float32), ((BATCH,), jnp.int32))
+                      ((BATCH, seq), jnp.float32), strip,
+                      ((BATCH, seq), jnp.float32), ((BATCH,), jnp.int32))
     assert _mosaic(hlo)
 
 

@@ -90,7 +90,7 @@ def run_grid(cfg, enc, plan, waves, *, kv_policies, fault_rates,
     for pol_name in kv_policies:
         kvp = kvcache.get_kv_policy(pol_name)
         # per-request attribution on every path (fused/chunked kernels
-        # reduce flags per batch row in-grid since bench_kernels/v5)
+        # reduce flags per batch row in-grid)
         kvp = dataclasses.replace(kvp, per_slot_flags=True)
         step = jax.jit(protected.make_serve_step(
             cfg, plan=plan, with_flags=True, kv_policy=kvp))

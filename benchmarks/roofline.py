@@ -121,11 +121,9 @@ def analyze(rec):
     }
 
 
-def fused_vs_decode_rows(bench_path="BENCH_kernels.json", m=128):
+def fused_vs_decode_rows(m=128):
     """Structural roofline bound for the fused decode+matmul vs the XLA
-    decode-then-matmul path, per autotune shape — the bound the measured
-    BENCH_kernels ``fused_us`` / ``fused_ref_us`` / ``fused_int8_us``
-    numbers compare against.
+    decode-then-matmul path at two (K, N) weight shapes.
 
     fused (raw int8): HBM traffic = a (M*K int8) + enc (K*N uint8) +
             out (M*N*4); decode never round-trips through HBM.
@@ -136,14 +134,8 @@ def fused_vs_decode_rows(bench_path="BENCH_kernels.json", m=128):
             activation traffic) + bf16 out (M*N*2 — half the f32 out),
             int8 MXU peak (2x the bf16 MACs/s).
     """
-    shapes = [(1024, 1024), (2048, 4096)]
-    try:
-        with open(bench_path) as f:
-            shapes = [tuple(e["shape"]) for e in json.load(f)["entries"]]
-    except (OSError, KeyError, ValueError):
-        pass
     rows = []
-    for k, n in shapes:
+    for k, n in ((1024, 1024), (2048, 4096)):
         flops = 2 * m * k * n
         fused_bytes = m * k + k * n + m * n * 4
         split_bytes = fused_bytes + 2 * k * n

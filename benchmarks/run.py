@@ -7,11 +7,10 @@ import sys
 
 
 def main() -> None:
-    from . import fault_injection, kernel_bench, weight_distribution, \
-        wot_admm_compare, wot_training
+    from . import fault_injection, weight_distribution, wot_admm_compare, \
+        wot_training
 
     print("name,us_per_call,derived")
-    kernel_bench.main()
     weight_distribution.main()
     wot_training.main()
     fault_injection.main([])  # explicit argv: don't inherit run.py's

@@ -89,7 +89,7 @@ KV_SCHEMES = ("faulty", "parity-zero", "in-place")
 
 # ~VMEM per TPU core (v4/v5 class) — the budget the strip kernel's whole
 # gathered working set must fit inside, and the denominator of the
-# structural crossover recorded by benchmarks/kernel_bench.py.
+# structural crossover that strip_vmem_crossover computes.
 VMEM_BUDGET_BYTES = 16 * 2 ** 20
 
 # lanes of a grid cell's (1, 128) flag row: corrected and detected blocks

@@ -12,8 +12,7 @@ weights never exist whole on the device.
       --backend pallas --kv-policy in-place-fused          # one TPU chip
   PYTHONPATH=src python -m repro.launch.serve --arch deepseek-7b --smoke \
       --fault-rate 1e-4 --tokens 32 [--scheme in-place] [--backend xla] \
-      [--policy attn-inplace-mlp-secded] [--autotune BENCH_kernels.json] \
-      [--abft] [--act-clamp]
+      [--policy attn-inplace-mlp-secded] [--abft] [--act-clamp]
 
 ``--abft`` turns on in-kernel ABFT checksum verification for every
 protected matmul (compute-fault detection next to the memory-fault ECC
@@ -23,9 +22,8 @@ clamps into the same epilogue. Both report through the ``*_abft`` flags
 channel after the run.
 
 ``--policy`` serves under a named mixed-scheme preset: the materialized
-``ProtectionPlan`` decides scheme and backend per leaf (``--autotune``
-feeds the shape-keyed backend table), and the serve step decodes each
-leaf accordingly — one model, many schemes, many backends.
+``ProtectionPlan`` decides scheme and backend per leaf, and the serve step
+decodes each leaf accordingly — one model, many schemes, many backends.
 """
 from __future__ import annotations
 
@@ -171,8 +169,6 @@ def main():
                     choices=sorted(protection.POLICY_PRESETS),
                     help="serve under a named mixed-scheme preset "
                          "(overrides --scheme)")
-    ap.add_argument("--autotune", default=None, metavar="BENCH_kernels.json",
-                    help="shape-keyed backend table for per-leaf dispatch")
     ap.add_argument("--kv-policy", default=None,
                     choices=sorted(kvcache.KV_POLICY_PRESETS),
                     help="serve against the paged protected KV cache under "
@@ -224,12 +220,10 @@ def main():
     params = lm.param_specs(cfg, jnp.bfloat16)
     if args.policy:
         policy = protection.get_policy_preset(args.policy,
-                                              backend=args.backend,
-                                              autotune=args.autotune)
+                                              backend=args.backend)
     else:
         policy = protection.ProtectionPolicy(default_scheme=args.scheme,
-                                             backend=args.backend,
-                                             autotune=args.autotune)
+                                             backend=args.backend)
     plan = policy.plan(params)
     s = plan.summary()
     print("[serve] " +

@@ -24,11 +24,8 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
-from .backends import (BACKENDS, BENCH_KERNELS_SCHEMA,
-                       BENCH_KERNELS_SCHEMA_V1, BENCH_KERNELS_SCHEMA_V2,
-                       BENCH_KERNELS_SCHEMA_V3, BENCH_KERNELS_SCHEMA_V4,
-                       BENCH_KERNELS_SCHEMA_V5, AutotuneTable, Backend,
-                       PallasBackend, XlaBackend, get_backend)
+from .backends import (BACKENDS, Backend, PallasBackend, XlaBackend,
+                       get_backend)
 from .campaign import (CampaignResult, accuracy_eval, compute_campaign,
                        due_campaign, due_eval, fidelity_campaign,
                        fidelity_eval, run_campaign, run_campaign_host)
@@ -55,9 +52,6 @@ __all__ = [
     "decode_tree_with_flags", "inject_tree", "inject_tree_device",
     "spec_tree", "space_overhead", "ProtectedWeight", "can_fuse",
     "Backend", "XlaBackend", "PallasBackend", "BACKENDS", "get_backend",
-    "AutotuneTable", "BENCH_KERNELS_SCHEMA", "BENCH_KERNELS_SCHEMA_V1",
-    "BENCH_KERNELS_SCHEMA_V2", "BENCH_KERNELS_SCHEMA_V3",
-    "BENCH_KERNELS_SCHEMA_V4", "BENCH_KERNELS_SCHEMA_V5",
     "HostScheme", "Stored", "get_host_scheme", "run_fault_trial",
     "CampaignResult", "run_campaign", "run_campaign_host",
     "fidelity_campaign", "due_campaign", "compute_campaign", "accuracy_eval",

@@ -69,9 +69,6 @@ class ProtectedWeight:
 
     pt:         the (already per-layer-sliced) ProtectedTensor.
     backend:    Backend instance or name for this leaf's codec compute.
-    tiles:      optional (bm, bn, bk) for the fused float path (autotune);
-                None uses the kernel defaults (full-K tiles).
-    int8_tiles: optional (bm, bn, 0) for the fused int8 epilogue.
     record:     ``record(corrected, due)`` flags callback (no-op when None).
     act_quant:  None (float activations) | "dynamic" (per-token absmax) |
                 "static" (needs ``a_scale``) — the int8 MXU serve path.
@@ -93,8 +90,6 @@ class ProtectedWeight:
     decode_at_use = True  # the marker layers._proj dispatches on
 
     def __init__(self, pt: ProtectedTensor, backend="xla", *,
-                 tiles: Optional[tuple] = None,
-                 int8_tiles: Optional[tuple] = None,
                  record: Optional[Callable] = None,
                  act_quant: Optional[str] = None,
                  a_scale: Optional[float] = None,
@@ -111,8 +106,6 @@ class ProtectedWeight:
         self.pt = pt
         self.backend = get_backend(backend)
         self.fuse = can_fuse(pt, self.backend)
-        self.tiles = tiles
-        self.int8_tiles = int8_tiles
         self.act_quant = act_quant
         self.a_scale = a_scale
         self.abft = bool(abft)
@@ -188,10 +181,9 @@ class ProtectedWeight:
         exact int32 accumulator scaled by ``a_scale * w_scale`` in f32."""
         if self.fuse:
             from repro.kernels.ecc_qmatmul import ecc_qmatmul
-            bm, bn, _bk = (self.int8_tiles or self.tiles or (128, 128, 0))
             res = ecc_qmatmul(q_x, self.pt.enc, self.pt.scale,
                               a_scale=a_scale, out_dtype=out_dtype,
-                              bm=bm, bn=bn, with_flags=True,
+                              with_flags=True,
                               with_abft=self.abft, clamp=self.clamp)
             if self._track:
                 out, flags, (rows, col_mm) = res
@@ -280,12 +272,10 @@ class ProtectedWeight:
             self.record_abft(row_mm, hits, col_mm)
             return acc.astype(x.dtype).reshape(*lead, n_out)
         from repro.kernels.ecc_qmatmul import ecc_qmatmul
-        # serving keeps full-K tiles (bk=0): one f32 dot per output tile, so
-        # the accumulation order — and hence every logit — is bit-identical
-        # to decode-then-matmul. The autotune bk only tunes the int8 path.
-        bm, bn, _bk = self.tiles or (128, 128, 0)
-        res = ecc_qmatmul(a2, self.pt.enc, self.pt.scale,
-                          bm=bm, bn=bn, bk=0, with_flags=True,
+        # the kernel's default full-K tiles give one f32 dot per output
+        # tile, so the accumulation order — and hence every logit — is
+        # bit-identical to decode-then-matmul
+        res = ecc_qmatmul(a2, self.pt.enc, self.pt.scale, with_flags=True,
                           with_abft=self.abft, clamp=self.clamp)
         if self._track:
             out, flags, (rows, col_mm) = res

@@ -5,8 +5,8 @@ earns (or is denied) the in-place (64,57,1) code.  A :class:`ProtectionPlan`
 makes that concrete — it is built ONCE from ``(policy, abstract_params,
 mesh?)`` and holds, for every leaf, the resolved :class:`LeafPlan`: scheme
 id, storage layout (same-shape vs flat-padded), resolved backend (per-leaf
-rules > shape-keyed autotune table > policy default), stored-bytes
-accounting, and the sharding spec of the stored image.  Every consumer —
+rules > policy default), stored-bytes accounting, and the sharding spec of
+the stored image.  Every consumer —
 ``ProtectionPolicy.encode_tree/decode_tree/coverage``, the protected serving
 step, the dry-run grid — is a view over the same plan, so "which protection,
 where, on which backend" is one inspectable artifact instead of scattered
@@ -62,7 +62,7 @@ class LeafPlan:
     reason:      why unprotected ("predicate" | "rule" | "unaligned"; "" when
                  protected).
     backend:     resolved backend *name* for this leaf's codec compute.
-    backend_src: where the backend came from ("rule" | "autotune" | "policy").
+    backend_src: where the backend came from ("rule" | "policy").
     layout:      "same-shape" | "flat-padded" | "raw" (unprotected).
     shape:       logical weight shape.
     n_weights:   element count.
@@ -74,11 +74,6 @@ class LeafPlan:
     spec:        sharding spec of the stored image (a ``ProtectedTensor`` of
                  ``PartitionSpec`` for protected leaves) or None when the
                  plan was built without ``param_spec_fn``.
-    tiles:       fused decode+matmul (bm, bn, bk) for this leaf's per-layer
-                 (K, N) = ``shape[-2:]`` matmul, from the policy's autotune
-                 table (None without a table / for non-matmul shapes).
-    int8_tiles:  int8-epilogue (bm, bn, 0) tiles, same resolution.
-    tiles_src:   where the tiles came from: "exact" | "nearest" | "".
     act_quant:   activation-quantization decision for the serve step:
                  None (float activations) | "dynamic" (per-token absmax) |
                  "static" (calibrated ``a_scale``). Set via
@@ -108,9 +103,6 @@ class LeafPlan:
     spec: Any = dataclasses.field(default=None, compare=False)
     backend_obj: Any = dataclasses.field(default=None, compare=False,
                                          repr=False)
-    tiles: Optional[tuple] = None
-    int8_tiles: Optional[tuple] = None
-    tiles_src: str = ""
     act_quant: Optional[str] = None
     a_scale: Optional[float] = None
     abft: bool = False
@@ -306,7 +298,6 @@ class ProtectionPlan:
             "by_backend": self.by_backend(),
             "n_flat_padded": sum(lp.layout == "flat-padded" for lp in prot),
             "n_flat_sharded": sum(lp.flat_sharded for lp in prot),
-            "tiles_src": self._count(prot, "tiles_src"),
             "act_quant": self._count(prot, "act_quant"),
             "n_abft": sum(lp.abft for lp in prot),
             "n_clamped": sum(lp.clamp is not None for lp in prot),
@@ -637,21 +628,7 @@ def make_plan(policy, params, *, mesh=None,
         enc_shape = shape if aligned else (n + pad,)
         checks = int((n + pad) * scheme.check_ratio)
         stored = n + pad + checks
-        be, be_src = policy.resolve_backend(p, shape)
-        # fused-kernel tiles for the per-layer matmul: stacked leaves
-        # (L, K, N) slice to (K, N) inside the scan, so the tile shape is
-        # always the trailing two dims
-        tiles = int8_tiles = None
-        tiles_src = ""
-        if policy.autotune is not None and len(shape) >= 2:
-            tiles, f_src = policy.autotune.lookup_tiles_src(shape[-2:])
-            int8_tiles, i_src = policy.autotune.lookup_tiles_src(
-                shape[-2:], key="int8_tiles")
-            # one marker per leaf: "exact" only when every resolved tile
-            # kind matched the shape; any extrapolation surfaces as "nearest"
-            srcs = {s for s in (f_src, i_src) if s}
-            tiles_src = ("nearest" if "nearest" in srcs
-                         else "exact" if srcs else "")
+        be, be_src = policy.resolve_backend(p)
         spec = None
         if param_spec_fn is not None:
             if aligned:
@@ -669,8 +646,7 @@ def make_plan(policy, params, *, mesh=None,
             backend_src=be_src, layout="same-shape" if aligned
             else "flat-padded", shape=shape, n_weights=n, enc_shape=enc_shape,
             pad_bytes=pad, check_bytes=checks, stored_bytes=stored, spec=spec,
-            backend_obj=be, tiles=tiles, int8_tiles=int8_tiles,
-            tiles_src=tiles_src)
+            backend_obj=be)
     return ProtectionPlan(policy, leaves,
                           mesh_axes=tuple(sizes) if sizes else None)
 
@@ -698,7 +674,7 @@ POLICY_PRESETS: dict = {
 
 def get_policy_preset(name: str, **overrides):
     """Build a named preset ``ProtectionPolicy``; extra kwargs override the
-    preset's (e.g. ``predicate=``, ``backend=``, ``autotune=``)."""
+    preset's (e.g. ``predicate=``, ``backend=``)."""
     from .policy import ProtectionPolicy
     try:
         kw = dict(POLICY_PRESETS[name])

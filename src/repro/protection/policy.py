@@ -20,7 +20,7 @@ import numpy as np
 
 from repro.core import faults, quant, wot
 
-from .backends import AutotuneTable, get_backend
+from .backends import get_backend
 from .schemes import Scheme, get_scheme
 from .tensor import ProtectedTensor, is_protected_tensor
 
@@ -156,18 +156,15 @@ class ProtectionPolicy:
                     route for 64-bit-block codec compute.
     backend_rules:  ordered ``(pattern, backend)`` pairs resolved per leaf
                     (first regex matching the leaf's path wins) — one model
-                    can mix backends per layer.
-    autotune:       an :class:`AutotuneTable` (or a BENCH_kernels.json path)
-                    consulted by shape when no backend rule matches; the
-                    policy-global ``backend`` stays the final fallback.
+                    can mix backends per layer; ``backend`` decides the
+                    leaves no rule matches.
     """
 
     def __init__(self, default_scheme: str = "in-place",
                  rules: Sequence = (),
                  predicate: Optional[Callable] = None,
                  *, pad: bool = True, throttle: bool = True,
-                 backend="xla", backend_rules: Sequence = (),
-                 autotune=None):
+                 backend="xla", backend_rules: Sequence = ()):
         get_scheme(default_scheme)  # validate eagerly
         self.default_scheme = default_scheme
         self.rules = [(re.compile(pat), sid) for pat, sid in rules]
@@ -180,9 +177,6 @@ class ProtectionPolicy:
         self.backend = get_backend(backend)
         self.backend_rules = [(re.compile(pat), get_backend(be))
                               for pat, be in backend_rules]
-        if isinstance(autotune, (str, bytes)):
-            autotune = AutotuneTable.from_json(autotune)
-        self.autotune = autotune
 
     # -- selection -----------------------------------------------------------
 
@@ -208,19 +202,15 @@ class ProtectionPolicy:
             return None, "unaligned"
         return sid, ""
 
-    def resolve_backend(self, path: str, shape) -> tuple:
-        """Per-leaf backend: first matching backend rule wins, then the
-        shape-keyed autotune table, then the policy default.
+    def resolve_backend(self, path: str) -> tuple:
+        """Per-leaf backend: the first matching backend rule, else the
+        policy default.
 
-        -> (Backend, source) with source "rule" | "autotune" | "policy".
+        -> (Backend, source) with source "rule" | "policy".
         """
         for pat, be in self.backend_rules:
             if pat.search(path):
                 return be, "rule"
-        if self.autotune is not None:
-            best = self.autotune.lookup(shape)
-            if best is not None:
-                return get_backend(best), "autotune"
         return self.backend, "policy"
 
     # -- the plan ------------------------------------------------------------
@@ -268,14 +258,14 @@ class ProtectionPolicy:
         return self.plan(params).encode_tree(params)
 
     def decode_tree(self, enc_tree, dtype=jnp.bfloat16):
-        """Decode with per-leaf backend resolution (rules + autotune)."""
-        if not self.backend_rules and self.autotune is None:
+        """Decode with per-leaf backend resolution (rules, then default)."""
+        if not self.backend_rules:
             return decode_tree(enc_tree, dtype, backend=self.backend)
 
         def dec(path, leaf):
             if not is_protected_tensor(leaf):
                 return leaf
-            be, _ = self.resolve_backend(path_str(path), leaf.orig_shape)
+            be, _ = self.resolve_backend(path_str(path))
             return decode_leaf(leaf, dtype, backend=be)
         return jax.tree_util.tree_map_with_path(
             dec, enc_tree, is_leaf=is_protected_tensor)

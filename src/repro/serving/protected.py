@@ -93,8 +93,8 @@ def init_encoded(cfg: ArchConfig, plan: protection.ProtectionPlan, key,
 
 
 class _Router:
-    """Per-leaf decode route: (backend, fused tiles, activation-quant mode)
-    from the plan (leaf rules > autotune > policy default) or from the
+    """Per-leaf decode route: (backend, activation-quant mode) from the
+    plan (leaf rules > policy default) or from the
     policy-wide ``backend`` when serving without a plan.
 
     act_quant: None (float activations) | "dynamic" | "static" (serve-step
@@ -110,8 +110,6 @@ class _Router:
                              f"(None, 'static', 'dynamic', 'plan')")
         self.plan = plan
         self.backend = protection.get_backend(backend)
-        self.autotune = getattr(getattr(plan, "policy", None),
-                                "autotune", None)
         self.act_quant = act_quant
         self.calibrate = calibrate
         self.abft_per_slot = abft_per_slot
@@ -143,10 +141,6 @@ class _Router:
             return lp.backend_obj or protection.get_backend(lp.backend)
         return self.backend
 
-    def tiles_for(self, shape, *, key="tiles"):
-        lookup = getattr(self.autotune, "lookup_tiles_src", None)
-        return lookup(shape, key=key)[0] if lookup is not None else None
-
     def act_for(self, path: str) -> tuple:
         """-> (act_quant mode | None, a_scale | None) for one leaf."""
         lp = self.plan.leaves.get(path) if self.plan is not None else None
@@ -175,18 +169,10 @@ class _Router:
                 pt, dtype, backend=be)
             L.record_flags(corrected, due)
             return w
-        lp = self.plan.leaves.get(path) if self.plan is not None else None
-        shape = tuple(pt.orig_shape)
-        tiles = (lp.tiles if lp is not None and lp.tiles is not None
-                 else self.tiles_for(shape))
-        int8_tiles = (lp.int8_tiles
-                      if lp is not None and lp.int8_tiles is not None
-                      else self.tiles_for(shape, key="int8_tiles"))
         aq, a_scale = self.act_for(path)
         abft, clamp = self.abft_for(path)
         return ProtectedWeight(
-            pt, be, tiles=tiles, int8_tiles=int8_tiles,
-            record=L.record_flags, act_quant=aq, a_scale=a_scale,
+            pt, be, record=L.record_flags, act_quant=aq, a_scale=a_scale,
             abft=abft, clamp=clamp, record_abft=L.record_abft,
             abft_per_slot=self.abft_per_slot,
             observe=(functools.partial(L.record_act, path)

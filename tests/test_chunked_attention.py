@@ -47,7 +47,7 @@ def _strips(rng, b, s, kv, hd, scheme, faults=()):
 
 
 def _tol(oracle):
-    """The shipped acceptance tolerance (same formula as kernel_bench)."""
+    """The acceptance tolerance: 2% of the oracle's largest magnitude."""
     return 0.02 * (np.abs(oracle).max() + 1e-6)
 
 

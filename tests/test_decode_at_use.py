@@ -1,7 +1,6 @@
 """Fused decode-at-use serving: kernel flags, per-leaf routing, and the
 numerical-identity acceptance — decode-at-use logits == decode-per-step
 baseline on a trained model, for mixed-scheme plans, on both backends."""
-import json
 
 import jax
 import jax.numpy as jnp
@@ -12,6 +11,7 @@ from repro import configs, protection
 from repro.core import ecc
 from repro.data import synthetic
 from repro.models import lm
+from repro.protection.fused import is_matmul_weight
 from repro.serving import protected
 
 
@@ -81,102 +81,6 @@ def test_fused_kernel_edge_tiles_and_float_path():
 
 
 # ---------------------------------------------------------------------------
-# autotune table: 4x-ratio boundary + v2 <-> v1 artifacts
-# ---------------------------------------------------------------------------
-
-
-def test_autotune_lookup_4x_ratio_boundary():
-    t = protection.AutotuneTable(
-        entries=[{"shape": [32, 256], "xla_us": 1.0, "pallas_us": 2.0,
-                  "best": "xla"}])  # 1024 blocks
-    assert t.lookup((8, 256)) == "xla"      # 256 blocks: ratio exactly 4.0
-    assert t.lookup((8, 248)) is None       # 248 blocks: ratio 4.13 > 4
-    assert t.lookup((512, 64)) == "xla"     # 4096 blocks: ratio exactly 0.25
-    assert t.lookup((520, 64)) is None      # 4160 blocks: just beyond 0.25
-
-
-def test_autotune_v3_tiles_and_v2_v1_backward_compat(tmp_path):
-    v3 = {"schema": protection.BENCH_KERNELS_SCHEMA, "platform": "cpu",
-          "entries": [{"shape": [256, 256], "xla_us": 5.0, "pallas_us": 3.0,
-                       "best": "pallas", "tiles": [128, 128, 0],
-                       "fused_us": 2.5, "int8_tiles": [64, 128, 0],
-                       "fused_int8_us": 1.5}]}
-    v2 = {"schema": protection.BENCH_KERNELS_SCHEMA_V2, "platform": "cpu",
-          "entries": [{"shape": [256, 256], "xla_us": 5.0, "pallas_us": 3.0,
-                       "best": "pallas", "tiles": [128, 128, 0],
-                       "fused_us": 2.5}]}
-    v1 = {"schema": protection.BENCH_KERNELS_SCHEMA_V1, "platform": "cpu",
-          "entries": [{"shape": [256, 256], "xla_us": 5.0, "pallas_us": 3.0,
-                       "best": "pallas"}]}
-    p3, p2, p1 = tmp_path / "v3.json", tmp_path / "v2.json", tmp_path / "v1.json"
-    p3.write_text(json.dumps(v3))
-    p2.write_text(json.dumps(v2))
-    p1.write_text(json.dumps(v1))
-    t3 = protection.AutotuneTable.from_json(p3)
-    assert t3.lookup((256, 256)) == "pallas"
-    assert t3.lookup_tiles((256, 256)) == (128, 128, 0)
-    assert t3.lookup_int8_tiles((256, 256)) == (64, 128, 0)
-    assert t3.to_dict()["schema"] == protection.BENCH_KERNELS_SCHEMA
-    # v2 artifacts still load: float tiles yes, int8 tiles no
-    t2 = protection.AutotuneTable.from_json(p2)
-    assert t2.lookup((256, 256)) == "pallas"
-    assert t2.lookup_tiles((256, 256)) == (128, 128, 0)
-    assert t2.lookup_tiles((128, 512)) == (128, 128, 0)  # nearest-by-blocks
-    assert t2.lookup_int8_tiles((256, 256)) is None
-    assert t2.to_dict()["schema"] == protection.BENCH_KERNELS_SCHEMA_V2
-    # v1 artifacts still load: backend opinion yes, tile opinion no
-    t1 = protection.AutotuneTable.from_json(p1)
-    assert t1.lookup((256, 256)) == "pallas"
-    assert t1.lookup_tiles((256, 256)) is None
-    assert t1.to_dict()["schema"] == protection.BENCH_KERNELS_SCHEMA_V1
-    # round-trip of a v3 table preserves both tile kinds
-    rt = protection.AutotuneTable.from_dict(t3.to_dict())
-    assert rt.lookup_tiles((256, 256)) == (128, 128, 0)
-    assert rt.lookup_int8_tiles((256, 256)) == (64, 128, 0)
-
-
-def test_autotune_tiles_nearest_fallback_with_source():
-    """Tiles are hints, not routes: unseen shapes fall back to the nearest
-    tile-bearing entry by block count with NO 4x cap (the backend lookup
-    keeps its cap), and the source marker says when that happened."""
-    t = protection.AutotuneTable(
-        entries=[{"shape": [32, 256], "xla_us": 1.0, "pallas_us": 2.0,
-                  "best": "xla", "tiles": [128, 256, 0],
-                  "int8_tiles": [64, 64, 0]},
-                 {"shape": [2048, 4096], "xla_us": 9.0, "pallas_us": 9.9,
-                  "best": "xla", "tiles": [128, 512, 128]}])
-    assert t.lookup_tiles_src((32, 256)) == ((128, 256, 0), "exact")
-    # far beyond the 4x window: backend has no opinion, tiles still resolve
-    assert t.lookup((9999, 9992)) is None
-    assert t.lookup_tiles_src((9999, 9992)) == ((128, 512, 128), "nearest")
-    # int8 tiles skip entries that don't carry them
-    assert t.lookup_tiles_src((9999, 9992), key="int8_tiles") == \
-        ((64, 64, 0), "nearest")
-    # the plan surfaces the marker
-    rng = np.random.default_rng(3)
-    params = {"wq": jnp.asarray(rng.normal(size=(32, 256)).astype(np.float32)),
-              "wo": jnp.asarray(rng.normal(size=(16, 64)).astype(np.float32))}
-    policy = protection.ProtectionPolicy(
-        predicate=lambda p, l: getattr(l, "ndim", 0) >= 2, autotune=t)
-    plan = protection.make_plan(policy, params)
-    assert plan["wq"].tiles == (128, 256, 0)
-    assert plan["wq"].tiles_src == "exact"
-    assert plan["wo"].tiles == (128, 256, 0)   # nearest by block count
-    assert plan["wo"].tiles_src == "nearest"
-    assert plan.summary()["tiles_src"] == {"exact": 1, "nearest": 1}
-
-
-def test_checked_in_artifact_is_v3_with_tiles():
-    import os
-    path = os.path.join(os.path.dirname(os.path.dirname(__file__)),
-                        "BENCH_kernels.json")
-    t = protection.AutotuneTable.from_json(path)
-    assert t.schema == protection.BENCH_KERNELS_SCHEMA
-    assert any(t.lookup_tiles(e["shape"]) for e in t.entries)
-    assert any(t.lookup_int8_tiles(e["shape"]) for e in t.entries)
-
-
-# ---------------------------------------------------------------------------
 # the acceptance: fused decode-at-use == decode-per-step, trained model,
 # mixed-scheme plan, both backends
 # ---------------------------------------------------------------------------
@@ -235,17 +139,20 @@ def test_decode_at_use_prefill_conv_archs(arch):
                           np.asarray(pre2(enc, toks, {}), np.float32))
 
 
-def test_autotune_tiles_keep_serve_identity():
-    """A plan with the checked-in autotune table (whose entries carry
-    bk != 0 tiles) must still serve bit-identical to the per-step baseline:
-    serving always uses full-K tiles for the float path."""
-    import os
-    cfg = configs.get_smoke("qwen1.5-4b")
+@pytest.mark.parametrize("arch", ["qwen1.5-4b", "deepseek-7b"])
+def test_benchmark_policy_serves_bit_identical(arch):
+    """The benchmark cells' policy: every matmul leaf takes the Pallas route
+    from the policy default, and the decode-at-use serve step (the fused
+    kernel at its own tiles) equals the per-step decode baseline."""
+    cfg = configs.get_smoke(arch)
     params = lm.init_params(cfg, jax.random.PRNGKey(4))
-    bench = os.path.join(os.path.dirname(os.path.dirname(__file__)),
-                         "BENCH_kernels.json")
-    policy = protection.ProtectionPolicy(backend="pallas", autotune=bench)
+    policy = protection.get_policy_preset("all-in-place", backend="pallas")
     plan = protected.make_plan(params, policy)
+    mats = [lp for lp in plan
+            if lp.protected and is_matmul_weight(lp.path)]
+    assert mats
+    assert {(lp.backend, lp.backend_src) for lp in mats} == {
+        ("pallas", "policy")}
     enc = plan.encode_tree(params)
     cache = lm.init_cache(cfg, 2, 32)
     tok = jnp.zeros((2, 1), jnp.int32)

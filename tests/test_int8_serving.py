@@ -149,24 +149,57 @@ def test_decode_once_matches_reference_across_m_grids():
 
 
 # ---------------------------------------------------------------------------
-# ProtectedWeight: int8 routes are bit-identical, fused vs inline
+# ProtectedWeight: fused routes are bit-identical to inline decode
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("mode", ["dynamic", "static"])
-def test_protected_weight_int8_fused_equals_inline(mode):
+@pytest.mark.parametrize("m,k,n", [
+    (5, 64, 136),      # ragged second N tile
+    (45, 200, 72),     # ragged M, K and N inside one tile each
+    (141, 104, 264),   # two M tiles and three N tiles, both ragged
+])
+def test_protected_weight_float_fused_equals_inline(m, k, n):
+    """Float activations: the fused Pallas route at the kernel's default
+    tiles equals decode-then-matmul bit for bit — the XLA route's decoded
+    weights under one f32-accumulated dot, rounded once. (XLA's CPU bf16
+    dot sums in a batch-size-dependent order from 128 rows on, so its own
+    ``x @ w`` is not a fixed reference there.)"""
+    from repro.protection.fused import ProtectedWeight
+    rng = np.random.default_rng(5)
+    w = jnp.asarray(_wot_weights(rng, (k, n)).astype(np.float32) * 0.01)
+    pt = protection.ProtectionPolicy().encode_leaf(w, "in-place")
+    x = jnp.asarray(rng.normal(size=(m, k)).astype(np.float32)
+                    ).astype(jnp.bfloat16)
+    fused = ProtectedWeight(pt, "pallas")
+    assert fused.fuse
+    out = fused.matmul(x)
+    w_dec = ProtectedWeight(pt, "xla").astype(x.dtype)
+    want = jnp.dot(x, w_dec, preferred_element_type=jnp.float32
+                   ).astype(x.dtype)
+    assert out.shape == (m, n) and out.dtype == x.dtype
+    assert np.array_equal(np.asarray(out, np.float32),
+                          np.asarray(want, np.float32))
+
+
+@pytest.mark.parametrize("mode,lead,k,n", [
+    ("dynamic", (3, 5), 64, 128),
+    ("static", (3, 5), 64, 128),
+    # M 141: two M tiles and three N tiles, both ragged
+    ("dynamic", (3, 47), 200, 264),
+    ("static", (3, 47), 200, 264),
+], ids=["dynamic", "static", "dynamic-ragged", "static-ragged"])
+def test_protected_weight_int8_fused_equals_inline(mode, lead, k, n):
     from repro.protection.fused import ProtectedWeight
     rng = np.random.default_rng(6)
-    k, n = 64, 128
     w = jnp.asarray(_wot_weights(rng, (k, n)).astype(np.float32) * 0.01)
     policy = protection.ProtectionPolicy()
     pt = policy.encode_leaf(w, "in-place")
-    x = jnp.asarray(rng.normal(size=(3, 5, k)).astype(np.float32)
+    x = jnp.asarray(rng.normal(size=(*lead, k)).astype(np.float32)
                     ).astype(jnp.bfloat16)
     kw = dict(act_quant=mode, a_scale=0.02 if mode == "static" else None)
     out_fused = ProtectedWeight(pt, "pallas", **kw).matmul(x)
     out_inline = ProtectedWeight(pt, "xla", **kw).matmul(x)
-    assert out_fused.shape == (3, 5, n)
+    assert out_fused.shape == (*lead, n)
     assert np.array_equal(np.asarray(out_fused, np.float32),
                           np.asarray(out_inline, np.float32))
     # and both equal the explicit quantize->decode->matmul reference
@@ -178,7 +211,7 @@ def test_protected_weight_int8_fused_equals_inline(mode):
     q = jnp.clip(jnp.round(x2 / a_scale), -127, 127).astype(jnp.int8)
     acc = ref.ecc_qmatmul_ref(q, pt.enc)
     want = (acc.astype(jnp.float32) * (a_scale * pt.scale)
-            ).astype(jnp.bfloat16).reshape(3, 5, n)
+            ).astype(jnp.bfloat16).reshape(*lead, n)
     assert np.array_equal(np.asarray(out_fused, np.float32),
                           np.asarray(want, np.float32))
 

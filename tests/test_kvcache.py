@@ -4,7 +4,6 @@ serving chain (prefill -> decode), live-pool injection and per-layer KV
 flags, KV fault campaigns, byte accounting, the plan-level KV knob, and
 the windowed-ring / ragged-length attention regressions it leans on."""
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -536,59 +535,3 @@ def test_plan_kv_policy_drives_serving(smoke_params):
     logits, cache2, flags = prefill(enc, cache2, toks)
     assert logits.shape == (b, 8, cfg.vocab_padded)
     assert "layers_kv" in flags and "k_pages" in cache2
-
-
-# ---------------------------------------------------------------------------
-# bench artifact: bench_kernels/v6 attention + long-context + ABFT rows
-# ---------------------------------------------------------------------------
-
-
-def test_autotune_attention_rows():
-    entry = {"shape": [256, 256], "xla_us": 1.0, "pallas_us": 2.0,
-             "best": "xla"}
-    row = {"shape": [2, 128, 2, 32], "scheme": "in-place",
-           "fused_us": 1.0, "ref_us": 2.0, "bitexact": True}
-    long_row = {"shape": [1, 8192, 1, 128], "scheme": "in-place",
-                "chunk_tokens": 2048, "chunked_us": 9.0, "strip_us": 8.0,
-                "strip_vmem_bytes": 17_000_000, "over_budget": True,
-                "oracle_max_abs_err": 1e-3, "tol": 2e-2,
-                "within_tol": True}
-    xo = {"head_dim": 128, "rep": 2, "vmem_budget_bytes": 16 * 2 ** 20,
-          "chunk_tokens": 2048, "tokens_by_scheme": {"in-place": 8113}}
-    t = protection.AutotuneTable.from_dict(
-        {"schema": "bench_kernels/v5", "platform": "cpu",
-         "entries": [entry], "attention": [row],
-         "attention_long": [long_row], "crossover": xo})
-    assert t.schema == protection.BENCH_KERNELS_SCHEMA_V5 == "bench_kernels/v5"
-    assert t.attention == [row]
-    assert t.attention_long == [long_row] and t.crossover == xo
-    rt = protection.AutotuneTable.from_dict(t.to_dict())
-    assert rt.attention == [row] and rt.attention_long == [long_row]
-    assert rt.crossover == xo
-    # v4 artifacts (attention rows, no long-context section) still load
-    v4 = protection.AutotuneTable.from_dict(
-        {"schema": protection.BENCH_KERNELS_SCHEMA_V4,
-         "entries": [entry], "attention": [row]})
-    assert v4.attention == [row] and v4.attention_long == []
-    assert v4.crossover is None
-    for old in (protection.BENCH_KERNELS_SCHEMA_V1,
-                protection.BENCH_KERNELS_SCHEMA_V2,
-                protection.BENCH_KERNELS_SCHEMA_V3):
-        legacy = protection.AutotuneTable.from_dict(
-            {"schema": old, "entries": [entry]})
-        assert legacy.attention == [] and legacy.lookup([256, 256]) == "xla"
-    with pytest.raises(ValueError, match="unsupported autotune schema"):
-        protection.AutotuneTable.from_dict({"schema": "bench_kernels/v9"})
-
-    checked_in = os.path.join(os.path.dirname(__file__), os.pardir,
-                              "BENCH_kernels.json")
-    shipped = protection.AutotuneTable.from_json(checked_in)
-    assert shipped.schema == protection.BENCH_KERNELS_SCHEMA == "bench_kernels/v6"
-    assert shipped.attention and all(r["bitexact"] for r in shipped.attention)
-    assert shipped.attention_long and shipped.crossover
-    assert all(r["within_tol"] for r in shipped.attention_long)
-    assert any(r["over_budget"] for r in shipped.attention_long)
-    # v6 ABFT twin rows: priced at the winning tiles for reporting, never
-    # consulted by the lookups
-    assert all(e.get("fused_abft_us") and e.get("fused_int8_abft_us")
-               for e in shipped.entries)

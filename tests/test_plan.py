@@ -1,8 +1,7 @@
 """``repro.protection.plan`` — materialized per-leaf protection decisions:
 summary-vs-CoverageReport byte agreement, mixed scheme+backend trees,
-backend resolution order (rule > autotune > policy), preset policies, and
+backend resolution order (first rule > policy default), preset policies, and
 the plan-driven serving step."""
-import json
 import os
 import subprocess
 import sys
@@ -112,64 +111,22 @@ def test_plan_rejects_mismatched_tree():
 
 
 # ---------------------------------------------------------------------------
-# backend resolution: rule > autotune > policy default
+# backend resolution: first matching rule > policy default
 # ---------------------------------------------------------------------------
 
 
-def _table():
-    return protection.AutotuneTable(
-        entries=[{"shape": [16, 64], "xla_us": 2.0, "pallas_us": 1.0,
-                  "best": "pallas"},
-                 {"shape": [512, 512], "xla_us": 1.0, "pallas_us": 9.0,
-                  "best": "xla"}])
-
-
-def test_backend_resolution_order():
+@pytest.mark.parametrize("default,rules,path,expect", [
+    ("pallas", [("special", "xla")], "special/w", ("xla", "rule")),
+    ("xla", [("blk", "pallas"), ("/w$", "xla")], "blk/w",
+     ("pallas", "rule")),                               # first rule wins
+    ("xla", [("special", "pallas")], "blk/w", ("xla", "policy")),
+    ("pallas", [("special", "xla")], "blk/w", ("pallas", "policy")),
+], ids=["rule-hit", "first-rule-wins", "xla-default", "pallas-default"])
+def test_backend_resolution_order(default, rules, path, expect):
     policy = protection.ProtectionPolicy(
-        backend_rules=[("special", "xla")], autotune=_table(), predicate=PRED)
-    be, src = policy.resolve_backend("special/w", (16, 64))
-    assert (be.name, src) == ("xla", "rule")          # rule beats autotune
-    be, src = policy.resolve_backend("blk/w", (16, 64))
-    assert (be.name, src) == ("pallas", "autotune")   # exact shape hit
-    be, src = policy.resolve_backend("blk/w", (4096, 8192))
-    assert (be.name, src) == ("xla", "policy")        # too far from any entry
-
-
-def test_autotune_nearest_nblocks_fallback():
-    t = _table()
-    assert t.lookup((16, 64)) == "pallas"
-    assert t.lookup((8, 128)) == "pallas"    # same 128 blocks, other shape
-    assert t.lookup((512, 520)) == "xla"     # near the 32768-block entry
-    assert t.lookup((65536, 8192)) is None   # >4x from everything
-
-
-def test_autotune_table_bench_kernels_roundtrip(tmp_path):
-    payload = {"schema": protection.BENCH_KERNELS_SCHEMA, "platform": "cpu",
-               "entries": [{"shape": [256, 256], "xla_us": 10.0,
-                            "pallas_us": 5.0, "best": "pallas"}]}
-    p = tmp_path / "BENCH_kernels.json"
-    p.write_text(json.dumps(payload))
-    t = protection.AutotuneTable.from_json(p)
-    assert t.lookup((256, 256)) == "pallas"
-    assert t.to_dict()["schema"] == protection.BENCH_KERNELS_SCHEMA
-    # a policy accepts the path directly
-    pol = protection.ProtectionPolicy(autotune=str(p), predicate=PRED)
-    assert pol.resolve_backend("w", (256, 256))[0].name == "pallas"
-    with pytest.raises(ValueError, match="schema"):
-        protection.AutotuneTable.from_dict({"schema": "bogus/v9"})
-    with pytest.raises(ValueError, match="unknown best backend"):
-        protection.AutotuneTable(entries=[{"shape": [8, 8], "best": "tpu"}])
-
-
-def test_checked_in_bench_kernels_artifact_loads():
-    """BENCH_kernels.json in the repo root is valid autotune input."""
-    path = os.path.join(os.path.dirname(os.path.dirname(__file__)),
-                        "BENCH_kernels.json")
-    t = protection.AutotuneTable.from_json(path)
-    assert len(t) >= 3
-    for e in t.entries:
-        assert e["best"] in ("xla", "pallas")
-        assert e["nblocks"] == int(np.prod(e["shape"])) // 8
+        backend=default, backend_rules=rules, predicate=PRED)
+    be, src = policy.resolve_backend(path)
+    assert (be.name, src) == expect
 
 
 # ---------------------------------------------------------------------------
